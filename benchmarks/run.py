@@ -21,6 +21,7 @@ from benchmarks import (ablation_formats, fig3_linearity, fig7_variability,
                         hw_projection, kernel_bench, paged_attn_bench,
                         roofline, serve_bench, table1_energy,
                         table2_comparison)
+from repro.launch.compile_cache import use_compile_cache
 
 MODULES = {
     "table1": table1_energy,
@@ -136,6 +137,7 @@ def check_regressions(summary, baseline_summary) -> list:
 def main() -> None:
     check = "--check" in sys.argv[1:]
     picks = [a for a in sys.argv[1:] if a in MODULES] or list(MODULES)
+    use_compile_cache()
     failures = []
     records = []
     print("name,value,note")

@@ -1,30 +1,25 @@
 """Kernel backend dispatch: ONE place that decides Pallas vs jnp reference.
 
 Every kernel in this package has two interchangeable implementations — a
-Pallas kernel (TPU; interpret mode on this CPU container) and a jnp
-reference that doubles as the differential-testing oracle. Which one runs
-used to be decided by ad-hoc ``os.environ`` reads scattered across
-modules; this config object centralizes the policy so tests and CI can
-flip the whole kernel layer per backend path in one move:
+Pallas kernel and a jnp reference that doubles as the differential-testing
+oracle. The backend decides which one runs:
 
-- ``TIMEFLOATS_PAGED_PALLAS=1`` routes the serving kernels (page gather,
-  fused paged attention, fused sampling) through Pallas.
-- ``PALLAS_INTERPRET`` (default ``1``) runs Pallas kernels in interpret
-  mode — the CPU container has no TPU; set ``0`` on real hardware.
+- on a TPU, every Pallas kernel runs, compiled;
+- on any other backend the jnp references run, and a Pallas kernel that
+  is asked for explicitly runs in interpret mode (nothing else can run it).
 
-``current()`` resolves the active policy (env unless overridden),
-``override(...)`` installs a scoped override (tests / benchmarks), and the
-per-call ``use_pallas=`` / ``interpret=`` kwargs on each kernel entry
-point still win over both. CI runs the kernel test files once per backend
-path (see .github/workflows/ci.yml) so the Pallas route is always
-exercised, never just the fallback.
+``current()`` resolves the active policy (the backend unless overridden),
+``override(...)`` installs a scoped override (tests, the chip smoke's
+reference drain), and the per-call ``use_pallas=`` / ``interpret=`` kwargs
+on each kernel entry point win over both.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 from typing import Iterator, Optional
+
+import jax
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,22 +27,22 @@ class KernelDispatch:
     """Resolved kernel-backend policy for one call."""
 
     use_pallas: bool   # Pallas kernel vs jnp reference
-    interpret: bool    # Pallas interpret mode (CPU) vs compiled (TPU)
+    interpret: bool    # Pallas interpret mode vs compiled (TPU)
 
 
 _OVERRIDE: list = []  # stack of KernelDispatch overrides (innermost last)
 
 
-def _env_dispatch() -> KernelDispatch:
-    return KernelDispatch(
-        use_pallas=os.environ.get("TIMEFLOATS_PAGED_PALLAS", "0") == "1",
-        interpret=os.environ.get("PALLAS_INTERPRET", "1") != "0",
-    )
+def backend_dispatch() -> KernelDispatch:
+    """The backend's own policy: compiled Pallas on a TPU, else references
+    (with interpret mode for any Pallas kernel a caller asks for)."""
+    on_tpu = jax.default_backend() == "tpu"
+    return KernelDispatch(use_pallas=on_tpu, interpret=not on_tpu)
 
 
 def current() -> KernelDispatch:
-    """The active policy: innermost ``override`` if any, else env flags."""
-    return _OVERRIDE[-1] if _OVERRIDE else _env_dispatch()
+    """The active policy: innermost ``override`` if any, else the backend's."""
+    return _OVERRIDE[-1] if _OVERRIDE else backend_dispatch()
 
 
 def resolve(use_pallas: Optional[bool] = None,

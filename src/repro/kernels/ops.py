@@ -3,9 +3,9 @@
 `timefloats_matmul(x, w, cfg)` is the drop-in used by
 core.timefloats.matmul(mode="pallas"): it quantizes operands (XLA ops — the
 elementwise field extraction fuses well and is not the hot spot), pads to
-tile multiples, and invokes the Pallas kernel. On this CPU container the
-kernel always runs in interpret mode; on TPU set ``interpret=False`` via
-``PALLAS_INTERPRET=0`` or the `interpret` argument.
+tile multiples, and invokes the Pallas kernel: compiled on a TPU, in
+interpret mode elsewhere (kernels/dispatch), unless ``interpret`` says
+otherwise.
 """
 from __future__ import annotations
 
@@ -18,20 +18,15 @@ from repro.core.timefloats import (
     DEFAULT,
     QuantizedOperand,
     TFConfig,
+    dequantize_input,
     matmul_separable_transposed,
     quantize_input,
     quantize_weight,
 )
+from repro.kernels import dispatch
 from repro.kernels import timefloats_matmul as kernel_mod
 
 Array = jax.Array
-
-
-def _interpret_default() -> bool:
-    # Centralized policy (kernels/dispatch): interpret unless on real TPU.
-    from repro.kernels import dispatch
-
-    return dispatch.current().interpret
 
 
 def _pad_to(a: Array, mults: tuple[int, ...], pad_value=0) -> Array:
@@ -43,7 +38,7 @@ def _pad_to(a: Array, mults: tuple[int, ...], pad_value=0) -> Array:
 
 def _rnd8(v: int) -> int:
     """Round tile dims up to a multiple of 8: sub-8 tiles are below any
-    TPU register tile, and jax 0.8.2's CPU interpreter miscompiles some
+    TPU register tile, and the Pallas interpreter has miscompiled some
     degenerate (m<=3, odd-n) tile shapes when the pallas_call is jitted
     with traced operands (bisected in tests/test_kernels.py — shapes like
     (2,1,9) returned a zero row)."""
@@ -69,7 +64,7 @@ def timefloats_matmul(
 ) -> Array:
     """f32/bf16 (M,K) @ (K,N) through the TimeFloats Pallas kernel."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = dispatch.current().interpret
     m_dim, n_dim = x.shape[0], w.shape[1]
     qx = quantize_input(x, cfg)
     qw = quantize_weight(w, cfg)
@@ -90,7 +85,7 @@ def quantized_matmul(
 ) -> Array:
     """Kernel invocation on pre-quantized operands; returns padded (M',N')."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = dispatch.current().interpret
     c, m_dim, blk = qx.q.shape
     n_dim = qw.q.shape[2]
     bm, bn, bc = _tile_sizes(m_dim, n_dim, c, bm, bn, bc)
@@ -104,50 +99,44 @@ def quantized_matmul(
 
 
 @partial(jax.jit,
-         static_argnames=("k_dim", "cfg", "bm", "bc", "bd", "interpret"))
+         static_argnames=("k_dim", "cfg", "bm", "bc", "tn", "interpret"))
 def timefloats_matmul_transposed(
     g: Array,
     qw: QuantizedOperand,
     *,
     k_dim: int,
     cfg: TFConfig = DEFAULT,
-    bm: int = 128,
-    bc: int = 4,
-    bd: int = 4,
+    bm: int = 256,
+    bc: int = 8,
+    tn: int = 512,
     interpret: bool | None = None,
 ) -> Array:
     """dx = g @ W^T (M,N)x(K,N planes) through the transposed-read kernel.
 
     ``qw`` is the *stored* weight in the exact layout the forward kernel
     consumed — no re-quantization, no materialized W^T (DESIGN.md §3). The
-    streamed gradient is quantized here, along its own contraction dim N.
+    streamed gradient is quantized here, along its own contraction dim N,
+    and handed to the kernel as its exact bf16 values.
     With an ADC configured the call falls back to the XLA reference
     (transposed reads are modeled ADC-free, so the numbers are identical;
     the kernel itself rejects adc_bits).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = dispatch.current().interpret
     if cfg.adc_bits is not None:
         return matmul_separable_transposed(g, qw, k_dim, cfg)
-    m_dim = g.shape[0]
-    qg = quantize_input(g, cfg)
-    d_chunks = qg.q.shape[0]
-    c_chunks, blk, _ = qw.q.shape
-
+    m_dim, n_dim = g.shape
+    gd = dequantize_input(quantize_input(g, cfg), n_dim)  # (M, N) bf16
+    c_chunks = qw.q.shape[0]
     bm = min(bm, _rnd8(m_dim))
-    bc = min(bc, max(c_chunks, 1))
-    bd = min(bd, max(d_chunks, 1))
-    qgq = _pad_to(qg.q, (bd, bm, blk))
-    qgs = _pad_to(qg.scale, (bd, bm), pad_value=1.0)
-    n_pad = qgq.shape[0] * blk
-    # Pad the stored planes along C (whole zero planes) and N (zero columns;
-    # the matching padded g chunks are zero as well, so nothing contributes).
-    qwq = _pad_to(qw.q, (bc, blk, 1))
-    qws = _pad_to(qw.scale, (bc, 1), pad_value=1.0)
-    if qwq.shape[2] < n_pad:
-        qwq = jnp.pad(qwq, ((0, 0), (0, 0), (0, n_pad - qwq.shape[2])))
-        qws = jnp.pad(qws, ((0, 0), (0, n_pad - qws.shape[1])),
-                      constant_values=1.0)
+    bc = min(bc, c_chunks)
+    tn = min(tn, -(-n_dim // 128) * 128)
+    # Zero gradient columns meet zero weight columns (scale-1 pad), and
+    # whole zero planes pad C: nothing padded contributes.
+    gd = _pad_to(gd, (bm, tn))
+    n_pad = gd.shape[1]
+    qwq = _pad_to(qw.q, (bc, 1, n_pad))
+    qws = _pad_to(qw.scale, (bc, n_pad), pad_value=1.0)
     dx = kernel_mod.timefloats_matmul_transposed_quantized(
-        qgq, qgs, qwq, qws, cfg=cfg, bm=bm, bc=bc, bd=bd, interpret=interpret)
+        gd, qwq, qws, cfg=cfg, bm=bm, bc=bc, tn=tn, interpret=interpret)
     return dx[:m_dim, :k_dim]

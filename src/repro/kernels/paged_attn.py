@@ -4,12 +4,13 @@ The PR 5 paged decode path was gather-then-attend: ``paged_view``
 materializes a dense ``(B, T*page, *feat)`` copy of every row's pages and
 the attention family runs a full softmax on top — a round trip through
 HBM that TimeFloats' stay-in-one-domain thesis says to avoid. This module
-fuses the two: the kernel walks the per-slot page table *in-kernel*, one
-grid program per (slot, kv-split). Each program dynamic-slice-loads its
-assigned pages straight from the shared pool (``pl.ds`` on the page id,
-the same idiom as kernels/paged.py), runs one online-softmax block over
-them, and emits partial ``(m, l, acc)`` split state; a final combine
-reduces the splits:
+fuses the two: the kernel walks the per-slot page table, grid (slot,
+kv-split, page-in-split). The table and lengths are scalar-prefetched
+into SMEM and the pools stay in HBM; each step's block index names the
+page the table holds, so the pipeline DMAs one page at a time (the same
+idiom as kernels/paged.py) and VMEM holds a few pages whatever the pool
+size. Each page is folded into the split's online-softmax state, and the
+partial ``(m, l, acc)`` split state is reduced by a final combine:
 
     m* = max_s m_s,   l* = sum_s l_s * exp(m_s - m*),
     out = sum_s acc_s * exp(m_s - m*) / max(l*, eps).
@@ -22,11 +23,11 @@ Two entry points cover the serving families:
   latent/rope queries against the ``(P, page, C)/(P, page, R)`` pools,
   scores = (q_lat·c_kv + q_rope·k_rope)·scale and values = c_kv.
 
-Both have a jnp *structural reference* that performs the exact same
-per-split block math (shared helpers, identical op order), so in
-interpret mode the Pallas kernel matches it **bitwise** — that is the
-oracle-differential gate in tests/test_paged_attn.py. The reference is
-also the production CPU path (dispatch.use_pallas=False): it is leaner
+Both have a jnp *structural reference* that softmaxes each split as one
+block; the kernel folds it page by page, so the two agree to f32
+reassociation — the oracle-differential gate in tests/test_paged_attn.py
+holds them to that. The reference is also the production path off a TPU
+(dispatch.use_pallas=False): it is leaner
 than the ``paged_view``+softmax composition and, driven by the engine's
 KV-extent cap (models/model.decode_step ``kv_cap``), only ever touches
 the live prefix of the table instead of all ``max_len`` positions.
@@ -34,7 +35,8 @@ the live prefix of the table instead of all ``max_len`` positions.
 Masking contract: a row attends to positions ``pos < lengths[b]``
 (decode append-at-end causal; ``lengths`` includes the new token).
 Length-0 rows return exact zeros. Page-table entries past a row's extent
-point at the trash page 0 — they are loaded but masked, never mixed in.
+point at the trash page 0 — never mixed in (the kernel skips pages wholly
+past the length; the reference masks them).
 
 Split count: ``n_splits`` must divide the table extent; ``None`` asks
 kernels/autotune for the cached per-(page, heads, head_dim) choice.
@@ -48,6 +50,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune, dispatch
 
@@ -57,8 +60,7 @@ _EPS = 1e-30
 
 
 # ---------------------------------------------------------------------------
-# Shared per-split block math — used VERBATIM by the Pallas kernel body and
-# (vmapped over the batch) by the jnp reference, so the two agree bitwise.
+# Per-split block math of the jnp reference (vmapped over the batch).
 # ---------------------------------------------------------------------------
 
 
@@ -169,27 +171,128 @@ def _gqa_ref(q, k_pool, v_pool, pt, lengths, *, scale: float, n_splits: int):
     return jnp.stack(ms, 1), jnp.stack(ls, 1), jnp.stack(accs, 1)
 
 
-def _gqa_kernel(ts: int, page: int, hkv: int, g: int, dk: int, dv: int,
-                scale: float):
-    def kernel(pt_ref, q_ref, len_ref, kp_ref, vp_ref, m_ref, l_ref,
-               acc_ref):
-        sidx = pl.program_id(1)
-        # Walk this split's page-table entries; each load is one dynamic
-        # slice of the shared pool at the referenced page id.
-        ks = [kp_ref[pl.ds(pt_ref[0, i], 1), :] for i in range(ts)]
-        vs = [vp_ref[pl.ds(pt_ref[0, i], 1), :] for i in range(ts)]
-        k = jnp.concatenate(ks, axis=0).astype(jnp.float32)
-        v = jnp.concatenate(vs, axis=0).astype(jnp.float32)
-        k = k.reshape(ts * page, hkv, dk)
-        v = v.reshape(ts * page, hkv, dv)
-        q = q_ref[0].astype(jnp.float32).reshape(hkv, g, dk)
-        m, l, acc = _attend_block_gqa(q, k, v, sidx * (ts * page),
-                                      len_ref[0, 0], scale)
-        m_ref[0, 0] = m.reshape(hkv * g)
-        l_ref[0, 0] = l.reshape(hkv * g)
-        acc_ref[0, 0] = acc.reshape(hkv * g, dv)
+def _online_page_update(s, v_of, valid, m_ref, l_ref, acc_ref):
+    """Fold one page into a split's online-softmax state (kernel side).
+
+    s (H, page) scaled scores; ``v_of(p)`` returns p (H, page) @ values
+    (H, Dv); valid (1, page). The refs hold m, l (H, 1) and acc (H, Dv)."""
+    s = jnp.where(valid, s, NEG)
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_old - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + v_of(p)
+    m_ref[...] = m_new
+
+
+def _nt(a, b):
+    """a (M, D) @ b (N, D)^T in f32 — the MXU's transposed-B form."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _page_walk(body, *, ts: int, page: int):
+    """Kernel prologue shared by the paged decoders: grid (row, split,
+    page-in-split). Zeroes the split state on the split's first page, and
+    runs ``body(start, length)`` only for pages holding live positions —
+    pages past a row's extent (trash page 0) are never folded in."""
+
+    def kernel(pt_ref, len_ref, *refs):
+        del pt_ref  # consumed by the index maps
+        m_ref, l_ref, acc_ref = refs[-3:]
+        row, split, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        start = (split * ts + j) * page
+        length = len_ref[row]
+
+        @pl.when(start < length)
+        def _fold():
+            body(*refs[:-3], start, length, m_ref, l_ref, acc_ref)
 
     return kernel
+
+
+def _paged_call(kernel, pt, lengths, operands, pools, out_dims, *, b: int,
+                n_splits: int, ts: int, interpret: bool):
+    """pallas_call over grid (row, split, page-in-split).
+
+    The page table and lengths are scalar-prefetched into SMEM; each pool
+    stays in HBM and the block index map DMAs exactly the page the table
+    names at each step (double-buffered by the pipeline), so VMEM holds a
+    few pages whatever the pool size. ``operands`` are per-row (B, H, D)
+    query arrays, resident across a row's steps; ``out_dims`` are the
+    feature widths of the (B, S, H, dim) split-state outputs (m, l, acc)."""
+    t = pt.shape[1]
+    h = operands[0].shape[1]
+
+    def row_block(x):
+        return pl.BlockSpec((pl.Squeezed(),) + x.shape[1:],
+                            lambda i, s, j, pt_r, ln_r: (i, 0, 0))
+
+    def page_block(x):
+        return pl.BlockSpec(
+            (pl.Squeezed(),) + x.shape[1:],
+            lambda i, s, j, pt_r, ln_r: (pt_r[i * t + s * ts + j], 0, 0))
+
+    def out_block(dim):
+        return pl.BlockSpec((pl.Squeezed(), pl.Squeezed(), h, dim),
+                            lambda i, s, j, pt_r, ln_r: (i, s, 0, 0))
+
+    m, l, acc = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_splits, ts),
+            in_specs=[row_block(x) for x in operands]
+            + [page_block(x) for x in pools],
+            out_specs=[out_block(d) for d in out_dims]),
+        out_shape=[jax.ShapeDtypeStruct((b, n_splits, h, d), jnp.float32)
+                   for d in out_dims],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(pt.astype(jnp.int32).reshape(-1), lengths.astype(jnp.int32),
+      *operands, *pools)
+    return m[..., 0], l[..., 0], acc
+
+
+def _gqa_body(hkv: int, g: int, dk: int, dv: int, page: int, scale: float):
+    def body(q_ref, k_ref, v_ref, start, length, m_ref, l_ref, acc_ref):
+        q = q_ref[...].astype(jnp.float32)                 # (H, Dk)
+        k = k_ref[...].astype(jnp.float32)                 # (page, Hkv*Dk)
+        v = v_ref[...].astype(jnp.float32)                 # (page, Hkv*Dv)
+        # Query row r belongs to kv head r // g. Every kv head is scored
+        # against all H rows and the rows of its group kept: 2-D dots on
+        # lane-aligned head slices, no in-kernel head transpose.
+        grp = jax.lax.broadcasted_iota(jnp.int32, (hkv * g, 1), 0) // g
+        s = jnp.zeros((hkv * g, page), jnp.float32)
+        for kh in range(hkv):
+            s = jnp.where(grp == kh, _nt(q, k[:, kh * dk:(kh + 1) * dk]), s)
+
+        def v_of(p):
+            out = jnp.zeros((hkv * g, dv), jnp.float32)
+            for kh in range(hkv):
+                out = jnp.where(grp == kh,
+                                _nn(p, v[:, kh * dv:(kh + 1) * dv]), out)
+            return out
+
+        valid = start + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1) \
+            < length
+        _online_page_update(s * scale, v_of, valid, m_ref, l_ref, acc_ref)
+
+    return body
 
 
 @partial(jax.jit, static_argnames=("scale", "n_splits", "interpret"))
@@ -198,34 +301,14 @@ def _gqa_pallas(q, k_pool, v_pool, pt, lengths, *, scale: float,
     b, h, dk = q.shape
     p, page, hkv, _ = k_pool.shape
     dv = v_pool.shape[-1]
-    g = h // hkv
-    t = pt.shape[1]
-    ts = t // n_splits
-    m, l, acc = pl.pallas_call(
-        _gqa_kernel(ts, page, hkv, g, dk, dv, scale),
-        grid=(b, n_splits),
-        in_specs=[
-            pl.BlockSpec((1, ts), lambda i, j: (i, j)),
-            pl.BlockSpec((1, h * dk), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((p, page * hkv * dk), lambda i, j: (0, 0)),
-            pl.BlockSpec((p, page * hkv * dv), lambda i, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, h), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, h), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, h, dv), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_splits, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_splits, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_splits, h, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(pt.astype(jnp.int32), q.reshape(b, h * dk),
-      lengths.reshape(b, 1).astype(jnp.int32),
-      k_pool.reshape(p, page * hkv * dk), v_pool.reshape(p, page * hkv * dv))
-    return m, l, acc
+    ts = pt.shape[1] // n_splits
+    kernel = _page_walk(_gqa_body(hkv, h // hkv, dk, dv, page, scale),
+                        ts=ts, page=page)
+    return _paged_call(kernel, pt, lengths, [q],
+                       [k_pool.reshape(p, page, hkv * dk),
+                        v_pool.reshape(p, page, hkv * dv)],
+                       (1, 1, dv), b=b, n_splits=n_splits, ts=ts,
+                       interpret=interpret)
 
 
 def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
@@ -285,62 +368,31 @@ def _mla_ref(q_lat, q_rope, ckv_pool, kr_pool, pt, lengths, *, scale: float,
     return jnp.stack(ms, 1), jnp.stack(ls, 1), jnp.stack(accs, 1)
 
 
-def _mla_kernel(ts: int, page: int, h: int, c: int, r: int, scale: float):
-    def kernel(pt_ref, ql_ref, qr_ref, len_ref, cp_ref, rp_ref, m_ref,
-               l_ref, acc_ref):
-        sidx = pl.program_id(1)
-        cs = [cp_ref[pl.ds(pt_ref[0, i], 1), :] for i in range(ts)]
-        rs = [rp_ref[pl.ds(pt_ref[0, i], 1), :] for i in range(ts)]
-        ckv = jnp.concatenate(cs, axis=0).astype(jnp.float32)
-        kr = jnp.concatenate(rs, axis=0).astype(jnp.float32)
-        ckv = ckv.reshape(ts * page, c)
-        kr = kr.reshape(ts * page, r)
-        q_lat = ql_ref[0].astype(jnp.float32).reshape(h, c)
-        q_rope = qr_ref[0].astype(jnp.float32).reshape(h, r)
-        m, l, acc = _attend_block_mla(q_lat, q_rope, ckv, kr,
-                                      sidx * (ts * page), len_ref[0, 0],
-                                      scale)
-        m_ref[0, 0] = m
-        l_ref[0, 0] = l
-        acc_ref[0, 0] = acc
+def _mla_body(page: int, scale: float):
+    def body(ql_ref, qr_ref, c_ref, r_ref, start, length, m_ref, l_ref,
+             acc_ref):
+        ckv = c_ref[...].astype(jnp.float32)               # (page, C)
+        s = (_nt(ql_ref[...].astype(jnp.float32), ckv)
+             + _nt(qr_ref[...].astype(jnp.float32),
+                   r_ref[...].astype(jnp.float32)))        # (H, page)
+        valid = start + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1) \
+            < length
+        _online_page_update(s * scale, lambda p: _nn(p, ckv), valid,
+                            m_ref, l_ref, acc_ref)
 
-    return kernel
+    return body
 
 
 @partial(jax.jit, static_argnames=("scale", "n_splits", "interpret"))
 def _mla_pallas(q_lat, q_rope, ckv_pool, kr_pool, pt, lengths, *,
                 scale: float, n_splits: int, interpret: bool):
     b, h, c = q_lat.shape
-    r = q_rope.shape[-1]
-    p, page = ckv_pool.shape[:2]
-    t = pt.shape[1]
-    ts = t // n_splits
-    m, l, acc = pl.pallas_call(
-        _mla_kernel(ts, page, h, c, r, scale),
-        grid=(b, n_splits),
-        in_specs=[
-            pl.BlockSpec((1, ts), lambda i, j: (i, j)),
-            pl.BlockSpec((1, h * c), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, h * r), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((p, page * c), lambda i, j: (0, 0)),
-            pl.BlockSpec((p, page * r), lambda i, j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, h), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, h), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, h, c), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_splits, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_splits, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_splits, h, c), jnp.float32),
-        ],
-        interpret=interpret,
-    )(pt.astype(jnp.int32), q_lat.reshape(b, h * c), q_rope.reshape(b, h * r),
-      lengths.reshape(b, 1).astype(jnp.int32),
-      ckv_pool.reshape(p, page * c), kr_pool.reshape(p, page * r))
-    return m, l, acc
+    page = ckv_pool.shape[1]
+    ts = pt.shape[1] // n_splits
+    kernel = _page_walk(_mla_body(page, scale), ts=ts, page=page)
+    return _paged_call(kernel, pt, lengths, [q_lat, q_rope],
+                       [ckv_pool, kr_pool], (1, 1, c), b=b,
+                       n_splits=n_splits, ts=ts, interpret=interpret)
 
 
 def paged_decode_mla(q_lat: Array, q_rope: Array, ckv_pool: Array,

@@ -56,39 +56,28 @@ def argmax_low(x: Array, axis: int = -1) -> Array:
     return jnp.min(jnp.where(x == m, iota, n), axis=axis).astype(jnp.int32)
 
 
-def _sample_kernel(lg_ref, noise_ref, t_ref, out_ref):
-    """One grid program = one slot: masked argmax over its logit row
-    (lowest-index tie-break, matching the jnp oracle's `argmax_low`)."""
-    t = t_ref[0, 0]
-    lg = lg_ref[0]
-    v = lg.shape[0]
-    hot = lg / jnp.maximum(t, 1e-6) + noise_ref[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (v,), 0)
-
-    def low(x):
-        return jnp.min(jnp.where(x == jnp.max(x), iota, v))
-
-    pick = jnp.where(t > 0.0, low(hot), low(lg))
-    out_ref[0, 0] = pick.astype(jnp.int32)
+def _argmax_kernel(x_ref, out_ref):
+    """One grid program = one slot: argmax over its (1, V) row with the
+    lowest-index tie-break of the jnp oracle's `argmax_low`."""
+    x = x_ref[...]
+    v = x.shape[-1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    pick = jnp.min(jnp.where(x == jnp.max(x), iota, v))
+    out_ref[...] = jnp.full(out_ref.shape, pick, jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def _sample_pallas(lg: Array, noise: Array, temps: Array, *,
-                   interpret: bool) -> Array:
-    s, v = lg.shape
+def _argmax_pallas(x: Array, *, interpret: bool) -> Array:
+    s, v = x.shape
     out = pl.pallas_call(
-        _sample_kernel,
+        _argmax_kernel,
         grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, v), lambda i: (i, 0)),
-            pl.BlockSpec((1, v), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.int32),
+        in_specs=[pl.BlockSpec((pl.Squeezed(), 1, v), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((pl.Squeezed(), 1, 128), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((s, 1, 128), jnp.int32),
         interpret=interpret,
-    )(lg, noise, temps.reshape(s, 1).astype(jnp.float32))
-    return out[:, 0]
+    )(x.reshape(s, 1, v))
+    return out[:, 0, 0]
 
 
 def sample_tokens(logits: Array, temps: Array, key: Array, tags: Array,
@@ -124,8 +113,10 @@ def sample_tokens(logits: Array, temps: Array, key: Array, tags: Array,
                                  (logits.shape[-1],), jnp.float32)
 
     noise = jax.vmap(noise_one)(slots_iota, tags, counters)
+    # Tempered rows pick from the Gumbel-perturbed row, greedy rows from
+    # the logits; the selection is elementwise XLA shared by both paths,
+    # so the kernel and the reference argmax the same bits.
+    row = jnp.where((temps > 0.0)[:, None], lg / safe_t[:, None] + noise, lg)
     if d.use_pallas:
-        return _sample_pallas(lg, noise, temps, interpret=d.interpret)
-    hot = lg / safe_t[:, None] + noise
-    return jnp.where(temps > 0.0, argmax_low(hot, axis=-1),
-                     argmax_low(lg, axis=-1)).astype(jnp.int32)
+        return _argmax_pallas(row, interpret=d.interpret)
+    return argmax_low(row, axis=-1)

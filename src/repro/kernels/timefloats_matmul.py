@@ -28,8 +28,9 @@ ADC modeling: the kernel supports `adc_bits` with `adc_mode="fixed"` (static
 full-scale — bit-exact with the oracle). Dynamic auto-ranging needs a global
 max and is served by the XLA path (ops.py dispatches).
 
-Validated in interpret mode on CPU (tests/test_kernels.py) — the container
-has no TPU; see the harness contract.
+Compiled on a TPU; in interpret mode on every other backend
+(kernels/dispatch), where tests/test_kernels.py checks it against the
+oracle.
 """
 from __future__ import annotations
 
@@ -40,6 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.timefloats import TFConfig
+from repro.kernels import dispatch
 
 Array = jax.Array
 
@@ -75,13 +77,15 @@ def timefloats_matmul_quantized(
     bm: int = 256,
     bn: int = 256,
     bc: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Array:
     """pallas_call wrapper on pre-quantized/padded operands.
 
-    Expects M % bm == N % bn == C % bc == 0 (ops.py pads). interpret=True is
-    the validated CPU path; on real TPU pass interpret=False.
+    Expects M % bm == N % bn == C % bc == 0 (ops.py pads). ``interpret``
+    defaults to the backend's choice (kernels/dispatch).
     """
+    if interpret is None:
+        interpret = dispatch.current().interpret
     n_chunks, m_dim, blk = qx.shape
     n_dim = qw.shape[2]
     assert qw.shape == (n_chunks, blk, n_dim), (qx.shape, qw.shape)
@@ -115,82 +119,79 @@ def timefloats_matmul_quantized(
 # (DESIGN.md §3). The weight operand arrives in exactly the layout the
 # forward kernel consumed — (C, Bk, N) int8 planes with (C, N) scales — so
 # the backward pass re-reads the crossbar contents instead of re-quantizing
-# a materialized W^T. The streamed gradient is quantized along its own
-# contraction dim N: qg (D, M, Bn) int8, sg (D, M) f32 (D = N/Bn chunks).
+# a materialized W^T. The streamed gradient arrives already quantized along
+# its own contraction dim N and dequantized to bf16, in its natural (M, N)
+# layout: a 5-bit significand times a power-of-two scale is exact in bf16,
+# so this carries the same values as the (D, M, Bn) int8 planes plus
+# (D, M) scales, in a layout whose blocks the TPU tiles without slicing
+# lanes at 64-element offsets.
 #
-#     out: (M, C*Bk) f32,  out[m, (c,b)] = Σ_n gv[m,n] · qw[c,b,n] · sw[c,n]
+#     out: (M, C*Bk) f32,  out[m, (c,b)] = Σ_n g[m,n] · qw[c,b,n] · sw[c,n]
 #
 # The per-column weight scale sw[c, n] varies along the contraction, so it
 # cannot be hoisted into a rank-1 post-scale like the forward kernel's; the
-# kernel folds both scale sets into the operands (exact: 5-bit significands
-# times pow2 scales are lossless in f32) and accumulates an f32 MAC per
-# (d-chunk, c-plane) pair. Tiling: grid (M/bm, C/bc, D/bd), d innermost so
-# the (bm, bc*Bk) output tile stays resident across the N reduction.
+# kernel dequantizes the bc planes of one step into one (bc*Bk, tn) bf16
+# tile (again exact) and runs ONE transposed-B MXU pass against the
+# gradient tile. Tiling: grid (M/bm, C/bc, N/tn), n innermost so the
+# (bm, bc*Bk) output tile stays resident across the N reduction.
 # ---------------------------------------------------------------------------
 
 
-def _kernel_transposed(qg_ref, sg_ref, qw_ref, sw_ref, out_ref, *, bd: int,
-                       bc: int, blk_n: int):
-    """One (bm, bc*Bk) dx tile; accumulates bd gradient chunks per step."""
-    d = pl.program_id(2)
+def _kernel_transposed(g_ref, qw_ref, sw_ref, out_ref, *, bc: int):
+    """One (bm, bc*Bk) dx tile; accumulates one tn-wide slab of N."""
+    n = pl.program_id(2)
 
-    @pl.when(d == 0)
+    @pl.when(n == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    gv = [qg_ref[dd].astype(jnp.float32) * sg_ref[dd][:, None]
-          for dd in range(bd)]  # each (bm, Bn)
-    cols = []
-    for cc in range(bc):
-        acc = None
-        for dd in range(bd):
-            sl = slice(dd * blk_n, (dd + 1) * blk_n)
-            wv = (qw_ref[cc, :, sl].astype(jnp.float32)
-                  * sw_ref[cc, sl][None, :])  # (Bk, Bn)
-            p = jax.lax.dot_general(
-                gv[dd], wv, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bm, Bk)
-            acc = p if acc is None else acc + p
-        cols.append(acc)
-    out_ref[...] = out_ref[...] + jnp.concatenate(cols, axis=1)
+    # Planes stack along sublanes (Bk rows each), so the concatenation is
+    # tile-aligned; the products are exact in bf16 (see above).
+    w = jnp.concatenate(
+        [qw_ref[cc].astype(jnp.float32) * sw_ref[cc:cc + 1, :]
+         for cc in range(bc)], axis=0).astype(g_ref.dtype)  # (bc*Bk, tn)
+    out_ref[...] += jax.lax.dot_general(
+        g_ref[...], w, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def timefloats_matmul_transposed_quantized(
-    qg: Array, sg: Array, qw: Array, sw: Array,
+    g: Array, qw: Array, sw: Array,
     *,
     cfg: TFConfig,
-    bm: int = 128,
-    bc: int = 4,
-    bd: int = 4,
-    interpret: bool = True,
+    bm: int = 256,
+    bc: int = 8,
+    tn: int = 512,
+    interpret: bool | None = None,
 ) -> Array:
-    """pallas_call wrapper on pre-quantized/padded operands (ops.py pads).
+    """pallas_call wrapper on padded operands (ops.py quantizes and pads).
 
-    Expects M % bm == C % bc == D % bd == 0 and qw's N axis padded to
-    D * block. Returns the padded (M, C*Bk) dx; callers slice to k_dim.
+    g (M, N) bf16 holds the quantized gradient's exact values; qw (C, Bk, N)
+    int8 and sw (C, N) f32 are the stored planes. Expects M % bm == C % bc
+    == N % tn == 0. Returns the padded (M, C*Bk) dx; callers slice to k_dim.
     """
-    d_chunks, m_dim, blk_n = qg.shape
-    c_chunks, blk_k, n_pad = qw.shape
-    assert sg.shape == (d_chunks, m_dim) and sw.shape == (c_chunks, n_pad)
-    assert n_pad == d_chunks * blk_n, (qg.shape, qw.shape)
-    assert m_dim % bm == 0 and c_chunks % bc == 0 and d_chunks % bd == 0
+    if interpret is None:
+        interpret = dispatch.current().interpret
+    m_dim, n_pad = g.shape
+    c_chunks, blk_k, _ = qw.shape
+    assert qw.shape[2] == n_pad and sw.shape == (c_chunks, n_pad), (
+        g.shape, qw.shape, sw.shape)
+    assert m_dim % bm == 0 and c_chunks % bc == 0 and n_pad % tn == 0
 
     if cfg.adc_bits is not None:
         raise ValueError("transposed reads are modeled ADC-free (DESIGN.md "
                          "§3); the ADC applies to forward reads only")
 
-    grid = (m_dim // bm, c_chunks // bc, d_chunks // bd)
-    kernel = functools.partial(_kernel_transposed, bd=bd, bc=bc, blk_n=blk_n)
+    grid = (m_dim // bm, c_chunks // bc, n_pad // tn)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_kernel_transposed, bc=bc),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bd, bm, blk_n), lambda i, c, d: (d, i, 0)),
-            pl.BlockSpec((bd, bm), lambda i, c, d: (d, i)),
-            pl.BlockSpec((bc, blk_k, bd * blk_n), lambda i, c, d: (c, 0, d)),
-            pl.BlockSpec((bc, bd * blk_n), lambda i, c, d: (c, d)),
+            pl.BlockSpec((bm, tn), lambda i, c, n: (i, n)),
+            pl.BlockSpec((bc, blk_k, tn), lambda i, c, n: (c, 0, n)),
+            pl.BlockSpec((bc, tn), lambda i, c, n: (c, n)),
         ],
-        out_specs=pl.BlockSpec((bm, bc * blk_k), lambda i, c, d: (i, c)),
+        out_specs=pl.BlockSpec((bm, bc * blk_k), lambda i, c, n: (i, c)),
         out_shape=jax.ShapeDtypeStruct((m_dim, c_chunks * blk_k), jnp.float32),
         interpret=interpret,
-    )(qg, sg, qw, sw)
+    )(g, qw, sw)

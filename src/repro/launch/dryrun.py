@@ -333,7 +333,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
     t0 = time.time()
     jitted, args = build_cell(arch, shape, mesh, quant=quant, accum=accum,
                               variant=variant)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -10,6 +10,7 @@ drain a synthetic request stream, then print the latency/throughput report
 """
 import argparse
 import dataclasses
+import json
 import sys
 import time
 
@@ -55,6 +56,9 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the metrics registry snapshot (.json = "
                          "flat dict, else Prometheus text)")
+    ap.add_argument("--tokens-out", default=None, metavar="PATH",
+                    help="write each request's generated tokens as JSON "
+                         "({uid: [tokens]})")
     ap.add_argument("--trace-capacity", type=int, default=1 << 16,
                     help="tracer ring size; overflow voids the trace's "
                          "energy certification")
@@ -83,6 +87,7 @@ def main(argv=None):
     import jax
 
     from repro.configs import get_config, reduced_for_smoke
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import model as M
     from repro.obs.export import (validate_health, validate_trace,
                                   write_chrome_trace, write_metrics)
@@ -92,6 +97,7 @@ def main(argv=None):
     from repro.serve.request import Request, percentile as _pct
     from repro.serve.spec import SpecConfig
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_for_smoke(cfg)
@@ -278,6 +284,10 @@ def main(argv=None):
     if args.metrics_out:
         write_metrics(args.metrics_out, eng.metrics)
         print(f"metrics written to {args.metrics_out}")
+    if args.tokens_out:
+        with open(args.tokens_out, "w") as f:
+            json.dump({str(r.uid): [int(t) for t in r.tokens] for r in done},
+                      f)
     if args.trace_out:
         meta = {"hw": hw, "engine": args.engine, "arch": args.arch}
         if health_doc is not None:

@@ -13,6 +13,7 @@ restartable data).
 """
 import argparse
 import dataclasses
+import math
 import os
 import sys
 
@@ -59,11 +60,14 @@ def main(argv=None):
     from repro.configs import get_config, reduced_for_smoke
     from repro.core.timefloats import TFConfig
     from repro.data.pipeline import DataPipeline
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_mesh
     from repro.optim.optimizers import OptimizerConfig
     from repro.parallel import sharding as shd
     from repro.train import step as tsl
     from repro.train.trainer import LoopConfig, run_loop
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_for_smoke(cfg)
@@ -83,7 +87,9 @@ def main(argv=None):
                  3: ("pod", "data", "model")}[len(dims)]
     else:
         dims, names = (n_dev,), ("data",)
-    mesh = jax.make_mesh(dims, names)
+    # A mesh smaller than the host (--mesh 1 on four chips) takes the first
+    # devices.
+    mesh = make_mesh(dims, names, devices=jax.devices()[:math.prod(dims)])
     rules = shd.make_rules(mesh)
     print(f"mesh {dict(zip(names, dims))} over {n_dev} devices; "
           f"arch={args.arch} quant={args.quant} "
@@ -95,6 +101,13 @@ def main(argv=None):
     s_shard = shd.tree_shardings(s_axes, jax.tree.map(lambda a: a, state),
                                  mesh, rules)
     state = jax.device_put(state, s_shard)
+    per_dev = {d: 0 for d in mesh.devices.flat}
+    for leaf in jax.tree.leaves(state):
+        for shard in leaf.addressable_shards:
+            per_dev[shard.device] += shard.data.nbytes
+    total = sum(leaf.nbytes for leaf in jax.tree.leaves(state))
+    print(f"state bytes: total {total}, per device "
+          f"{[per_dev[d] for d in mesh.devices.flat]}")
 
     pipe = DataPipeline(cfg, batch=args.batch, seq=args.seq, seed=args.seed,
                         kind="markov" if cfg.vocab_size <= 65536 else "lm")
@@ -141,7 +154,7 @@ def main(argv=None):
 
     loop = LoopConfig(total_steps=args.steps, log_every=args.log_every,
                       ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir)
-    with mesh:
+    with jax.set_mesh(mesh):
         state, report = run_loop(state, jitted, pipe.batch_at, loop,
                                  restore_shardings=s_shard,
                                  on_metrics=on_metrics,

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel import sharding
-
 Array = jax.Array
 PyTree = Any
 
@@ -80,9 +78,8 @@ def pipeline_forward(
         return jax.lax.psum(buf * mask, axis)
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    return sharding.shard_map(run, mesh=mesh, in_specs=in_specs,
-                              out_specs=P(),
-                              check_vma=False)(stage_params, x_micro)
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(stage_params, x_micro)
 
 
 def split_stages(stacked_params: PyTree, n_stages: int) -> PyTree:

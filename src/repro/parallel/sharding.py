@@ -38,18 +38,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 PyTree = Any
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map across jax versions: top-level `jax.shard_map` with
-    `check_vma` on current jax, `jax.experimental.shard_map.shard_map` with
-    the older `check_rep` spelling on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
     "vocab": ("model",),
     "heads": ("model",),

@@ -79,7 +79,8 @@ import jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 mgr = CheckpointManager({str(tmp_path)!r})
 target = {{"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}}
 sh = {{"w": NamedSharding(mesh, P("data", "model"))}}

@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweeps against the ref.py oracle
-(interpret=True on CPU, per the harness contract)."""
+(interpret mode, which the backend dispatch picks off a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

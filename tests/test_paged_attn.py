@@ -3,14 +3,16 @@
 
 Contracts under test:
 
-- **Oracle differential, bitwise**: the Pallas split-K kernels
+- **Oracle differential**: the Pallas split-K kernels
   (:func:`paged_decode_attention`, :func:`paged_decode_mla`), run in
-  interpret mode on this container, are BIT-IDENTICAL to the jnp
-  structural reference — same per-split block math, same combine
-  executable — across page sizes {4, 8, 16}, head grids, split counts,
-  ragged lengths (including 0 and single-page), trash-page-0 tables and
-  both pool dtypes. Deterministic cases always run; a hypothesis fuzz
-  widens the net when the optional dep is installed.
+  interpret mode, match the jnp structural reference to f32 rounding
+  (``assert_oracle``: the kernel folds a split page by page with online
+  rescaling where the reference softmaxes the split as one block; both
+  accumulate in f32, so they differ only in summation order) across page
+  sizes {4, 8, 16}, head grids, split counts, ragged lengths (including
+  0 and single-page), trash-page-0 tables and both pool dtypes. Length-0
+  rows are exact zeros on both. Deterministic cases always run; a
+  hypothesis fuzz widens the net when the optional dep is installed.
 - **KV-extent cap neutrality**: slicing the page table to any prefix
   that covers every row's length does not change a single bit — the
   engine's pow2 cap schedule is therefore numerics-free.
@@ -26,8 +28,8 @@ Contracts under test:
 - **Launch/compile counts**: decode_and_sample stays ONE jitted launch
   per engine step; cap variants compile once each (a handful of pow2
   caps, not one per step) and a second drain adds ZERO new compiles.
-- **Dispatch policy**: env flags, `override()` scoping, and per-call
-  kwargs compose in that priority order.
+- **Dispatch policy**: the backend's choice, `override()` scoping, and
+  per-call kwargs compose in that priority order.
 """
 import dataclasses
 
@@ -76,6 +78,20 @@ def tolerance_report(got, want) -> dict:
 def assert_bitwise(got, want, label: str = "") -> None:
     rep = tolerance_report(got, want)
     assert rep["exact"], f"{label} not bitwise: {rep}"
+
+
+# Kernel vs structural reference: the same f32 arithmetic summed in another
+# order (page-by-page online softmax vs one block per split). A few ulp of
+# f32 (2^-23 ~ 1.2e-7) per accumulated page bounds the gap on these O(1)
+# outputs; observed max_abs ~2.4e-7.
+ORACLE_RTOL, ORACLE_ATOL = 1e-5, 1e-6
+
+
+def assert_oracle(got, want, label: str = "") -> None:
+    rep = tolerance_report(got, want)
+    ok = np.allclose(np.asarray(got), np.asarray(want), rtol=ORACLE_RTOL,
+                     atol=ORACLE_ATOL)
+    assert ok, f"{label} outside f32 reassociation tolerance: {rep}"
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +156,8 @@ MLA_CASES = [
 
 @pytest.mark.parametrize("seed,case", list(enumerate(GQA_CASES)))
 def test_gqa_kernel_matches_oracle_bitwise(seed, case):
-    """Pallas split-K GQA decode (interpret) == jnp reference, bitwise."""
+    """Pallas split-K GQA decode (interpret) == jnp reference to f32
+    reassociation; empty rows exactly zero on both."""
     b, t, page, hkv, g, dk, dv, dtype, ns = case
     rng = np.random.default_rng(seed)
     q, kp, vp, pt, lens = _gqa_case(rng, b, t, page, hkv, g, dk, dv, dtype)
@@ -148,13 +165,16 @@ def test_gqa_kernel_matches_oracle_bitwise(seed, case):
                                   use_pallas=False)
     got = paged_decode_attention(q, kp, vp, pt, lens, n_splits=ns,
                                  use_pallas=True, interpret=True)
-    assert_bitwise(got, want, f"gqa{case}")
-    assert np.all(np.asarray(want)[np.asarray(lens) == 0] == 0.0)
+    assert_oracle(got, want, f"gqa{case}")
+    empty = np.asarray(lens) == 0
+    assert np.all(np.asarray(want)[empty] == 0.0)
+    assert np.all(np.asarray(got)[empty] == 0.0)
 
 
 @pytest.mark.parametrize("seed,case", list(enumerate(MLA_CASES)))
 def test_mla_kernel_matches_oracle_bitwise(seed, case):
-    """Pallas split-K absorbed-MLA decode (interpret) == jnp ref, bitwise."""
+    """Pallas split-K absorbed-MLA decode (interpret) == jnp ref to f32
+    reassociation; empty rows exactly zero on both."""
     b, t, page, h, c, r, dtype, ns = case
     rng = np.random.default_rng(seed)
     ql, qr, cp, rp, pt, lens = _mla_case(rng, b, t, page, h, c, r, dtype)
@@ -162,8 +182,10 @@ def test_mla_kernel_matches_oracle_bitwise(seed, case):
                             n_splits=ns, use_pallas=False)
     got = paged_decode_mla(ql, qr, cp, rp, pt, lens, scale=0.125,
                            n_splits=ns, use_pallas=True, interpret=True)
-    assert_bitwise(got, want, f"mla{case}")
-    assert np.all(np.asarray(want)[np.asarray(lens) == 0] == 0.0)
+    assert_oracle(got, want, f"mla{case}")
+    empty = np.asarray(lens) == 0
+    assert np.all(np.asarray(want)[empty] == 0.0)
+    assert np.all(np.asarray(got)[empty] == 0.0)
 
 
 def test_gqa_oracle_matches_dense_softmax():
@@ -207,7 +229,7 @@ def test_kv_cap_is_bitwise_neutral():
 @given(st.data())
 def test_gqa_kernel_oracle_fuzz(data):
     """Property fuzz (hypothesis): random shape/dtype/split/ragged-length
-    draws, Pallas-interpret vs reference, bitwise."""
+    draws, Pallas-interpret vs reference (``assert_oracle``)."""
     b = data.draw(st.integers(1, 3), label="b")
     t = data.draw(st.sampled_from([1, 2, 4, 8]), label="t")
     page = data.draw(st.sampled_from([4, 8, 16]), label="page")
@@ -224,7 +246,7 @@ def test_gqa_kernel_oracle_fuzz(data):
                                   use_pallas=False)
     got = paged_decode_attention(q, kp, vp, pt, lens, n_splits=ns,
                                  use_pallas=True, interpret=True)
-    assert_bitwise(got, want)
+    assert_oracle(got, want)
 
 
 @settings(max_examples=25, deadline=None)
@@ -245,7 +267,7 @@ def test_mla_kernel_oracle_fuzz(data):
                             n_splits=ns, use_pallas=False)
     got = paged_decode_mla(ql, qr, cp, rp, pt, lens, scale=0.125,
                            n_splits=ns, use_pallas=True, interpret=True)
-    assert_bitwise(got, want)
+    assert_oracle(got, want)
 
 
 # ---------------------------------------------------------------------------
@@ -306,12 +328,11 @@ def test_fused_sampling_audio_path_matches_legacy():
 
 
 def test_dispatch_priority(monkeypatch):
-    """env < override < per-call kwargs, and override scoping restores."""
-    monkeypatch.delenv("TIMEFLOATS_PAGED_PALLAS", raising=False)
-    monkeypatch.delenv("PALLAS_INTERPRET", raising=False)
+    """backend < override < per-call kwargs, and override scoping restores.
+    Off a TPU the references run and Pallas interprets; on a TPU every
+    kernel runs compiled."""
     assert dispatch.current() == dispatch.KernelDispatch(False, True)
-    monkeypatch.setenv("TIMEFLOATS_PAGED_PALLAS", "1")
-    monkeypatch.setenv("PALLAS_INTERPRET", "0")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert dispatch.current() == dispatch.KernelDispatch(True, False)
     with dispatch.override(use_pallas=False):
         assert dispatch.current() == dispatch.KernelDispatch(False, False)

@@ -47,12 +47,26 @@ def test_resolve_spec_multi_axis_batch():
     assert s == P(("pod", "data"), None)
 
 
+def test_make_mesh_axis_types_auto():
+    """Launcher meshes are Auto-typed (JAX 0.9's make_mesh defaults to
+    Explicit, under which the FSDP-sharded dot raises) and take a device
+    subset when asked."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
+
+
 def test_zero_shard_spec():
     code = """
 import jax
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel.zero import zero_shard_spec
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 # fully replicated 2D state -> first divisible dim gets "data"
 # (specs are rank-padded, so compare against the padded form)
 s = zero_shard_spec(P(), (8, 6), mesh, axes=("data",))
@@ -75,16 +89,17 @@ def test_compression_error_feedback_unbiased():
 import jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.parallel import compression, sharding
+from repro.launch.mesh import make_mesh
+from repro.parallel import compression
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 grads_seq = [
     {"w": jax.random.normal(jax.random.PRNGKey(s), (4, 33))}
     for s in range(20)
 ]
 
 def one_step(g, state):
-    f = sharding.shard_map(
+    f = jax.shard_map(
         lambda g_, e_: compression.compressed_psum_tree(
             g_, compression.CompressionState(error=e_), "pod"),
         mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod"), P()),
@@ -120,8 +135,9 @@ import jax, numpy as np
 import jax.numpy as jnp
 from repro.parallel.pipeline import (pipeline_forward, split_stages,
                                      make_layer_stage_fn)
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 L, D, M, B = 8, 16, 6, 4
 key = jax.random.PRNGKey(0)
 params = {"w": jax.random.normal(key, (L, D, D)) / np.sqrt(D)}
@@ -179,9 +195,10 @@ from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.train import step as tsl
 from repro.data.synthetic import lm_batch
+from repro.launch.mesh import make_mesh
 
 cfg = reduced_for_smoke(get_config("qwen3-0.6b"))
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = shd.make_rules(mesh)
 tcfg = tsl.TrainConfig(accum=2)
 state = tsl.init_state(cfg, tcfg, jax.random.PRNGKey(0))
@@ -196,7 +213,7 @@ def fn(s, b):
     with shd.sharding_context(mesh, rules):
         return step_fn(s, b)
 jitted = jax.jit(fn, in_shardings=(s_shard, b_shard), donate_argnums=(0,))
-with mesh:
+with jax.set_mesh(mesh):
     new_state, metrics = jitted(state, batch)
 loss = float(metrics["loss"])
 assert np.isfinite(loss), loss
@@ -219,6 +236,7 @@ from repro.models import model as M
 from repro.parallel import sharding as shd
 from repro.train import step as tsl
 from repro.data.synthetic import lm_batch
+from repro.launch.mesh import make_mesh
 
 cfg = reduced_for_smoke(get_config("phi3-mini-3.8b"))
 cfg = dataclasses.replace(cfg, quant="none")
@@ -229,7 +247,7 @@ step_fn = tsl.make_train_step(cfg, tcfg)
 _, m_single = jax.jit(step_fn)(state, batch)
 l_single = float(m_single["loss"])
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = shd.make_rules(mesh)
 state2 = tsl.init_state(cfg, tcfg, jax.random.PRNGKey(0))
 s_axes = tsl.state_axes(cfg, tcfg)
@@ -240,7 +258,7 @@ batch2 = jax.device_put(batch, b_shard)
 def fn(s, b):
     with shd.sharding_context(mesh, rules):
         return step_fn(s, b)
-with mesh:
+with jax.set_mesh(mesh):
     _, m_shard = jax.jit(fn, in_shardings=(s_shard, b_shard))(state2, batch2)
 l_shard = float(m_shard["loss"])
 assert abs(l_single - l_shard) < 5e-3, (l_single, l_shard)
