@@ -1,4 +1,5 @@
-"""Bit-identity of the quantized-operand cache (DESIGN.md §3).
+"""Bit-identity of the quantized-operand cache (DESIGN.md §3), at the level
+of one linear layer.
 
 The contract: caching changes *when* quantization happens, never *what* it
 produces. Cached (quantized residuals / precomputed weight entries) and
@@ -8,13 +9,13 @@ bit-identical to the pre-cache implementation (whose backward re-decomposed
 w.T / x.T — elementwise decomposition is transpose-equivariant, so only the
 separable plane layouts changed semantics, and those by design).
 
-The scanned-stack suite at the bottom extends the contract to whole models:
-with the weight cache threaded through the grouped layer scans (stacked
-PreparedOperands as scan xs, DESIGN.md §3), loss AND grads must equal the
-TFConfig.cache=False execution for every layer family, in all three modes,
-including under remat.
+The MoE layer stack is checked here too, op by op with jit disabled, where
+every leaf of the gradients must match bit for bit. The jitted scanned
+stacks are in test_cache_stack*.py and test_cache_remat.py; serving and the
+train step in test_cache_model.py.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +31,8 @@ from repro.core import timefloats as tf
 from repro.core.timefloats import TFConfig
 from repro.models import common
 
-MODES = ["exact", "separable", "pallas"]
+from _model_helpers import (MODES, family_batch, family_cfg,
+                            loss_and_grads, mlp_model_cfg)
 
 
 def _data(key=0, lead=(3, 5), k=96, n=10):
@@ -42,9 +44,52 @@ def _data(key=0, lead=(3, 5), k=96, n=10):
 
 
 def _run(fn, x, w, g):
-    y, vjp = jax.vjp(fn, x, w)
-    dx, dw = vjp(g)
-    return np.asarray(y), np.asarray(dx), np.asarray(dw)
+    """y, dx and dW of fn at (x, w) for cotangent g, as one jitted program
+    (the contract is about values; eager dispatch only costs time)."""
+    def fwd_bwd(x, w, g):
+        y, vjp = jax.vjp(fn, x, w)
+        return (y, *vjp(g))
+
+    return tuple(np.asarray(a) for a in jax.jit(fwd_bwd)(x, w, g))
+
+
+def _primal(fn, *args):
+    """fn(*args) outside autodiff, jitted like _run."""
+    return np.asarray(jax.jit(fn)(*args))
+
+
+# prepare_weight of one weight, and of a stack of them over the leading
+# dims (vmapped once per dim), jitted once per shape and mode (the stacking
+# law is about values).
+_prepare = jax.jit(tf.prepare_weight, static_argnums=1)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _prepare_stacked(w, cfg, stack_dims=1):
+    prep = functools.partial(tf.prepare_weight, cfg=cfg)
+    for _ in range(stack_dims):
+        prep = jax.vmap(prep)
+    return prep(w)
+
+
+# First in the file: it takes minutes, and the linear tests after it keep
+# the file's last tests short (a test worker is handed its next file while
+# it runs a file's last two tests). Op by op, the stack runs as about 2,900
+# distinct single-op programs, each compiled once: that count, not the
+# batch or the number of experts, sets its time, hence the raised limit.
+@pytest.mark.time_limit(900)
+def test_stacked_cache_moe_bit_identical_op_by_op():
+    """Op-by-op (jit disabled), cached vs uncached MoE loss AND grads are
+    bit-identical on EVERY leaf — the tolerance in the jitted comparison
+    covers XLA's program-dependent dot reduction order, not our math."""
+    cfg_c = family_cfg("moe", "separable", cache=True)
+    cfg_u = family_cfg("moe", "separable", cache=False)
+    batch = family_batch(cfg_c)
+    with jax.disable_jit():
+        lc, gc = loss_and_grads(cfg_c, batch, jit=False)
+        lu, gu = loss_and_grads(cfg_u, batch, jit=False)
+    np.testing.assert_array_equal(lc, lu)
+    jax.tree.map(np.testing.assert_array_equal, gc, gu)
 
 
 @pytest.mark.parametrize("mode", MODES)
@@ -66,7 +111,7 @@ def test_fwd_primal_matches_vjp_fwd(mode):
     path must reproduce _scaled_matmul bit-for-bit)."""
     x, w, g = _data(key=1)
     cfg = TFConfig(mode=mode)
-    y_p = np.asarray(tf.linear(x, w, cfg))
+    y_p = _primal(lambda a, b: tf.linear(a, b, cfg), x, w)
     y_f, _, _ = _run(lambda a, b: tf.linear(a, b, cfg), x, w, g)
     np.testing.assert_array_equal(y_p, y_f)
 
@@ -93,10 +138,11 @@ def test_exact_mode_matches_precache_backward():
     _, dx, dw = _run(lambda a, b: tf.linear(a, b, cfg), x, w, g)
     g2 = g.reshape(-1, g.shape[-1])
     x2 = x.reshape(-1, x.shape[-1])
-    legacy_dx = tf._scaled_matmul(g2, w.T, cfg).reshape(x.shape)
-    legacy_dw = tf._scaled_matmul(x2.T, g2, cfg)
-    np.testing.assert_array_equal(dx, np.asarray(legacy_dx))
-    np.testing.assert_array_equal(dw, np.asarray(legacy_dw))
+    legacy_dx = _primal(lambda a, b: tf._scaled_matmul(a, b, cfg),
+                        g2, w.T).reshape(x.shape)
+    legacy_dw = _primal(lambda a, b: tf._scaled_matmul(a, b, cfg), x2.T, g2)
+    np.testing.assert_array_equal(dx, legacy_dx)
+    np.testing.assert_array_equal(dw, legacy_dw)
 
 
 def test_separable_transposed_read_tracks_f32_gradients():
@@ -148,17 +194,10 @@ def test_adc_training_path_runs_through_cache():
 # ---------------------------------------------------------------------------
 
 
-def _mlp_model_cfg(mode="separable"):
-    from repro.configs import get_config, reduced_for_smoke
-
-    cfg = reduced_for_smoke(get_config("qwen3-0.6b"))
-    return dataclasses.replace(cfg, quant="timefloats", tf=TFConfig(mode=mode))
-
-
 def test_dense_weight_cache_scope_bit_identical():
     """common.dense under weight_cache_scope == without it, for values and
     for gradients through the params."""
-    model_cfg = _mlp_model_cfg()
+    model_cfg = mlp_model_cfg()
     kx, kw = jax.random.split(jax.random.PRNGKey(7))
     d = model_cfg.d_model
     params = {"w_up": jax.random.normal(kw, (d, 2 * d))}
@@ -169,8 +208,8 @@ def test_dense_weight_cache_scope_bit_identical():
         with common.weight_cache_scope(p, cache):
             return jnp.sum(common.dense(x, p["w_up"], model_cfg) ** 2)
 
-    l0, g0 = jax.value_and_grad(lambda p: loss(p, False))(params)
-    l1, g1 = jax.value_and_grad(lambda p: loss(p, True))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(lambda p: loss(p, False)))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(lambda p: loss(p, True)))(params)
     np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
     np.testing.assert_array_equal(np.asarray(g0["w_up"]),
                                   np.asarray(g1["w_up"]))
@@ -180,7 +219,7 @@ def test_build_weight_cache_filters():
     """Embedding tables, norms, routers and conv kernels are excluded;
     dense projection weights are included (flat), scanned layer stacks get
     stacked entries (groups); quant='none' disables the cache."""
-    model_cfg = dataclasses.replace(_mlp_model_cfg(), tie_embeddings=False)
+    model_cfg = dataclasses.replace(mlp_model_cfg(), tie_embeddings=False)
     params = {
         "embed": jnp.ones((32, 8)),
         "groups": [{"params": {
@@ -194,7 +233,10 @@ def test_build_weight_cache_filters():
         "lm_head": jnp.ones((8, 32)),
         "norm": {"scale": jnp.ones((8,))},
     }
-    cache = common.build_weight_cache(params, model_cfg)
+    # only which leaves are cached and the entries' shapes are read, so
+    # nothing needs quantizing
+    cache = jax.eval_shape(
+        lambda p: common.build_weight_cache(p, model_cfg), params)
     assert isinstance(cache, common.WeightCache)
     assert sorted(cache.flat) == ["['lm_head']"]
     assert len(cache.groups) == 1
@@ -217,7 +259,7 @@ def test_build_weight_cache_filters():
 def test_build_weight_cache_tied_head_entry():
     """Tied-embedding configs get a transposed-read head entry keyed on the
     embed leaf (the table itself stays gather-read / uncached)."""
-    model_cfg = _mlp_model_cfg()
+    model_cfg = mlp_model_cfg()
     assert model_cfg.tie_embeddings
     params = {"embed": jnp.ones((32, 8)), "norm": {"scale": jnp.ones((8,))}}
     cache = common.build_weight_cache(params, model_cfg)
@@ -275,9 +317,9 @@ def test_stacking_law_smoke():
     for mode in MODES:
         cfg = TFConfig(mode=mode)
         w = jax.random.normal(jax.random.PRNGKey(2), (4, 70, 6)) * 3.0
-        stacked = jax.vmap(lambda wi: tf.prepare_weight(wi, cfg))(w)
+        stacked = _prepare_stacked(w, cfg)
         for i in range(4):
-            per = tf.prepare_weight(w[i], cfg)
+            per = _prepare(w[i], cfg)
             jax.tree.map(
                 lambda a, b: np.testing.assert_array_equal(
                     np.asarray(a), np.asarray(b)),
@@ -285,253 +327,41 @@ def test_stacking_law_smoke():
     # expert rule: (layers, E, d, f) -> vmap over layers of vmap over E
     cfg = TFConfig(mode="separable")
     we = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 64, 5))
-    stacked = jax.vmap(jax.vmap(lambda wi: tf.prepare_weight(wi, cfg)))(we)
-    per = tf.prepare_weight(we[1, 2], cfg)
+    stacked = _prepare_stacked(we, cfg, 2)
+    per = _prepare(we[1, 2], cfg)
     jax.tree.map(
         lambda a, b: np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b)),
         jax.tree.map(lambda a: a[1, 2], stacked), per)
 
 
+# (layers, k, n): a fixed set, so each shape compiles once however many
+# examples draw it. It keeps the edges of the old ranges (layers 1-4,
+# k 1-130, n 1-9): 1-sized dims, K one short of / one past a 64-row block,
+# K spanning three blocks.
+STACK_SHAPES = [(1, 1, 1), (4, 130, 9), (2, 64, 1), (3, 65, 5),
+                (1, 127, 9), (4, 7, 3), (2, 129, 2)]
+
+
 @settings(max_examples=25, deadline=None)
 @given(mode=st.sampled_from(MODES),
-       layers=st.integers(min_value=1, max_value=4),
-       k=st.integers(min_value=1, max_value=130),
-       n=st.integers(min_value=1, max_value=9),
+       shape=st.sampled_from(STACK_SHAPES),
        scale_exp=st.integers(min_value=-6, max_value=6),
        seed=st.integers(min_value=0, max_value=2**31 - 1))
-def test_stacking_law_property(mode, layers, k, n, scale_exp, seed):
+def test_stacking_law_property(mode, shape, scale_exp, seed):
     """Property form of the stacking law: for any stack shape / scale /
     mode, the scan-threaded slice equals what the residual-level fallback
     would have computed from the raw slice, leaf-exact."""
+    layers, k, n = shape
     cfg = TFConfig(mode=mode)
     w = (jax.random.normal(jax.random.PRNGKey(seed), (layers, k, n))
          * (2.0 ** scale_exp))
     # sprinkle exact zeros: the nonzero plane must stack exactly too
     w = jnp.where(jnp.abs(w) < 0.1 * (2.0 ** scale_exp), 0.0, w)
-    stacked = jax.vmap(lambda wi: tf.prepare_weight(wi, cfg))(w)
+    stacked = _prepare_stacked(w, cfg)
     i = seed % layers
-    per = tf.prepare_weight(w[i], cfg)
+    per = _prepare(w[i], cfg)
     jax.tree.map(
         lambda a, b: np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b)),
         jax.tree.map(lambda a: a[i], stacked), per)
-
-
-# ---------------------------------------------------------------------------
-# Scanned-stack cross-family bit-identity (the tentpole, end to end)
-# ---------------------------------------------------------------------------
-
-FAMILIES = ["attention", "mla", "ssm", "hybrid", "moe"]
-
-
-def _family_cfg(family, mode, cache=True, remat="none"):
-    """Smallest grouped-scan config exercising `family`'s block stack."""
-    from repro.configs import get_config, reduced_for_smoke
-    from repro.configs.base import MLAConfig
-
-    arch = {"attention": "qwen3-0.6b", "mla": "deepseek-v3-671b",
-            "ssm": "mamba2-1.3b", "hybrid": "hymba-1.5b",
-            "moe": "deepseek-v3-671b"}[family]
-    cfg = reduced_for_smoke(get_config(arch))
-    tiny_mla = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
-                         qk_nope_head_dim=16, qk_rope_head_dim=8,
-                         v_head_dim=16)
-    ch = dict(d_model=64, vocab_size=128, quant="timefloats", remat=remat,
-              tf=TFConfig(mode=mode, cache=cache), q_block=32, kv_block=32)
-    if cfg.n_heads:
-        ch.update(n_heads=2, n_kv_heads=1, head_dim=32)
-    if cfg.d_ff:
-        ch["d_ff"] = 128
-    if family == "mla":
-        # pure MLA+MLP stack: drop the MoE FFN so the scatter-dispatch
-        # noise (see the moe notes below) stays out of this family's run
-        ch.update(family="dense", moe=None, n_layers=2, mla=tiny_mla)
-    if family == "moe":
-        ch["mla"] = tiny_mla
-        ch["moe"] = dataclasses.replace(
-            cfg.moe, num_experts=4, top_k=2, d_expert=32, shared_d_ff=32,
-            dense_d_ff=64)
-    if cfg.ssm:
-        ch["ssm"] = dataclasses.replace(cfg.ssm, d_state=8, head_dim=16,
-                                        chunk=16)
-    if cfg.hybrid:
-        ch["hybrid"] = dataclasses.replace(cfg.hybrid, meta_tokens=4,
-                                           sliding_window=16)
-    return dataclasses.replace(cfg, **ch)
-
-
-def _family_batch(cfg, b=2, s=8, seed=1):
-    k1, k2, _ = jax.random.split(jax.random.PRNGKey(seed), 3)
-    return {"tokens": jax.random.randint(k1, (b, s), 0, cfg.vocab_size),
-            "labels": jax.random.randint(k2, (b, s), 0, cfg.vocab_size),
-            "mask": jnp.ones((b, s), jnp.float32)}
-
-
-def _loss_and_grads(cfg, batch, jit=True):
-    """loss+grads exactly as train/step.py computes them: weight cache
-    built outside the grad trace, scope installed around the loss."""
-    from repro.models import model as model_lib
-
-    params = model_lib.init(cfg, jax.random.PRNGKey(0))
-
-    def loss(p):
-        wc = common.build_weight_cache(p, cfg)
-        with common.weight_cache_scope(p, wc):
-            return model_lib.loss_fn(p, batch, cfg)[0]
-
-    fn = jax.value_and_grad(loss)
-    if jit:
-        fn = jax.jit(fn)
-    l, g = fn(params)
-    return np.asarray(l), jax.tree.map(np.asarray, g)
-
-
-def _assert_grads_identical(family, gc, gu):
-    """Bitwise by default. The MoE dispatch region is compared to f32
-    reassociation tolerance ONLY: XLA compiles the token-contraction dW
-    dots adjacent to the scatter/gather dispatch with program-dependent
-    reduction order (the dW sum mixes per-token pow2 scales, so order
-    changes last bits; observed on wd/shared_wd, pre-existing at the
-    residual-cache level). test_stacked_cache_moe_bit_identical_op_by_op
-    proves the arithmetic itself is bit-identical."""
-    fc = jax.tree_util.tree_flatten_with_path(gc)[0]
-    fu = jax.tree_util.tree_flatten_with_path(gu)[0]
-    for (path, a), (_, b) in zip(fc, fu):
-        name = jax.tree_util.keystr(path)
-        if family == "moe" and "['ffn']" in name:
-            np.testing.assert_allclose(a, b, rtol=1e-2, atol=2e-3,
-                                       err_msg=name)
-        else:
-            np.testing.assert_array_equal(a, b, err_msg=name)
-
-
-@pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("family", FAMILIES)
-def test_stacked_cache_bit_identity(family, mode):
-    """Loss AND grads with the stacked scan cache on == TFConfig.cache=False
-    for every layer family, in every mode."""
-    cfg_c = _family_cfg(family, mode, cache=True)
-    cfg_u = _family_cfg(family, mode, cache=False)
-    batch = _family_batch(cfg_c)
-    lc, gc = _loss_and_grads(cfg_c, batch)
-    lu, gu = _loss_and_grads(cfg_u, batch)
-    np.testing.assert_array_equal(lc, lu)
-    _assert_grads_identical(family, gc, gu)
-
-
-@pytest.mark.parametrize("family,remat", [
-    ("attention", "dots"), ("attention", "full"), ("mla", "full"),
-    ("ssm", "dots"), ("hybrid", "dots"), ("moe", "full")])
-def test_stacked_cache_bit_identity_remat(family, remat):
-    """Same contract under jax.checkpoint remat of the scan body (the
-    stacked cache entries are scan xs = saved inputs, never recomputed)."""
-    cfg_c = _family_cfg(family, "separable", cache=True, remat=remat)
-    cfg_u = _family_cfg(family, "separable", cache=False, remat=remat)
-    batch = _family_batch(cfg_c)
-    lc, gc = _loss_and_grads(cfg_c, batch)
-    lu, gu = _loss_and_grads(cfg_u, batch)
-    np.testing.assert_array_equal(lc, lu)
-    _assert_grads_identical(family, gc, gu)
-
-
-def test_stacked_cache_moe_bit_identical_op_by_op():
-    """Op-by-op (jit disabled), cached vs uncached MoE loss AND grads are
-    bit-identical on EVERY leaf — the tolerance in the jitted comparison
-    covers XLA's program-dependent dot reduction order, not our math."""
-    cfg_c = _family_cfg("moe", "separable", cache=True)
-    cfg_u = _family_cfg("moe", "separable", cache=False)
-    batch = _family_batch(cfg_c)
-    with jax.disable_jit():
-        lc, gc = _loss_and_grads(cfg_c, batch, jit=False)
-        lu, gu = _loss_and_grads(cfg_u, batch, jit=False)
-    np.testing.assert_array_equal(lc, lu)
-    jax.tree.map(np.testing.assert_array_equal, gc, gu)
-
-
-def test_step_trace_contains_zero_weight_preparations():
-    """The acceptance check for the scanned-stack cache: tracing the full
-    fwd+bwd loss with the cache installed performs ZERO prepare_weight
-    calls — every weight quantization lives in build_weight_cache, which
-    train/step.py runs once per optimizer step outside the microbatch
-    scan. (prepare_* counters tick once per Python invocation, i.e. per
-    trace — a call inside the layer-scan body would execute per layer per
-    microbatch; with the stacked cache there are none at all.)"""
-    from repro.models import model as model_lib
-
-    for family in ("attention", "moe"):
-        cfg = _family_cfg(family, "separable", cache=True)
-        batch = _family_batch(cfg)
-        params = model_lib.init(cfg, jax.random.PRNGKey(0))
-        wcache = common.build_weight_cache(params, cfg)
-
-        def loss(p, cfg=cfg, batch=batch, wcache=wcache):
-            with common.weight_cache_scope(p, wcache):
-                return model_lib.loss_fn(p, batch, cfg)[0]
-
-        tf.reset_quant_trace_counts()
-        jax.jit(jax.value_and_grad(loss)).lower(params)
-        counts = tf.quant_trace_counts()
-        assert counts["prepare_weight"] == 0, (family, counts)
-
-        # control: without the weight cache the loss trace prepares
-        # weights at every dense call site (executed per layer per
-        # microbatch at run time)
-        cfg_u = _family_cfg(family, "separable", cache=False)
-
-        def loss_u(p, cfg=cfg_u, batch=batch):
-            return model_lib.loss_fn(p, batch, cfg)[0]
-
-        tf.reset_quant_trace_counts()
-        jax.jit(jax.value_and_grad(loss_u)).lower(params)
-        assert tf.quant_trace_counts()["prepare_weight"] > 0
-
-
-@pytest.mark.parametrize("family", ["attention", "ssm", "hybrid"])
-def test_decode_prefill_unchanged_by_cache(family):
-    """Serving is a training-path-free zone: prefill and decode_step
-    logits are bit-identical whether TFConfig.cache is on or off (no
-    weight_cache_scope is ever installed outside train/step.py)."""
-    from repro.models import model as model_lib
-
-    outs = {}
-    for cache in (True, False):
-        cfg = _family_cfg(family, "separable", cache=cache)
-        params = model_lib.init(cfg, jax.random.PRNGKey(0))
-        batch = _family_batch(cfg, b=2, s=8)
-        from repro.models.model import prefix_length
-        max_len = 8 + prefix_length(cfg) + 4
-        mc = model_lib.init_cache(cfg, 2, max_len)
-        logits_p, mc = model_lib.prefill(params, batch, cfg, mc)
-        steps = [np.asarray(logits_p)]
-        tok = jnp.argmax(logits_p[:, -1], axis=-1)[:, None]
-        for _ in range(3):
-            logits_d, mc = model_lib.decode_step(params, mc, tok, cfg)
-            steps.append(np.asarray(logits_d))
-            tok = jnp.argmax(logits_d[:, -1], axis=-1)[:, None]
-        outs[cache] = steps
-    for a, b in zip(outs[True], outs[False]):
-        np.testing.assert_array_equal(a, b)
-
-
-def test_train_step_with_weight_cache_learns():
-    """A jitted train step with the step-level weight cache installed (and
-    grad accumulation, so the cache is hoisted outside the microbatch scan)
-    still descends."""
-    from repro.data.pipeline import DataPipeline
-    from repro.optim.optimizers import OptimizerConfig
-    from repro.train.step import TrainConfig, init_state, make_train_step
-
-    cfg = dataclasses.replace(_mlp_model_cfg(), n_layers=1, vocab_size=32)
-    tcfg = TrainConfig(accum=2, optimizer=OptimizerConfig(
-        name="adamw", lr=3e-3, schedule="constant"))
-    state = init_state(cfg, tcfg, jax.random.PRNGKey(0))
-    step = jax.jit(make_train_step(cfg, tcfg))
-    pipe = DataPipeline(cfg, batch=8, seq=16, seed=0, kind="markov",
-                        prefetch=0)
-    losses = []
-    for i in range(10):
-        state, m = step(state, pipe.batch_at(i))
-        losses.append(float(m["loss"]))
-    assert np.isfinite(losses).all()
-    assert np.mean(losses[-3:]) < losses[0]

@@ -86,6 +86,14 @@ def test_tile_height_must_match_alignment_block():
 # ---------------------------------------------------------------------------
 
 
+def _param_and_cache_shapes(cfg):
+    """Shapes of the parameters and of their weight cache, with nothing
+    allocated or quantized: the mapper and the cache rules read shapes only."""
+    params = jax.eval_shape(lambda: M.init(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda p: common.build_weight_cache(p, cfg), params)
+    return params, cache
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_mapper_agrees_with_weight_cache_rules(arch):
     """The mapper places EXACTLY the leaves build_weight_cache prepares —
@@ -93,8 +101,7 @@ def test_mapper_agrees_with_weight_cache_rules(arch):
     stacked keys — so the crossbar inventory and the §3 quantized-operand
     cache can never disagree about what lives in the arrays."""
     cfg = _tf_cfg(reduced_for_smoke(get_config(arch)))
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    cache = common.build_weight_cache(params, cfg)
+    params, cache = _param_and_cache_shapes(cfg)
     pl = map_params(params, cfg)
 
     flat_placed = {lp.key for lp in pl.leaves if lp.group is None}
@@ -114,8 +121,7 @@ def test_mapper_shapes_match_prepared_operands():
     """Placed (rows, cols) equal the stored int8 plane geometry of the
     cache entry for flat dense/dense_in leaves (separable mode)."""
     cfg = _tf_cfg(reduced_for_smoke(get_config("deepseek-v3-671b")))
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    cache = common.build_weight_cache(params, cfg)
+    params, cache = _param_and_cache_shapes(cfg)
     pl = map_params(params, cfg)
     by_key = {lp.key: lp for lp in pl.leaves if lp.group is None}
     for key, ent in cache.flat.items():

@@ -12,7 +12,7 @@ from repro.launch import compile_cache
 BASE = os.path.join(os.path.dirname(__file__), "..")
 
 
-def run_cli(args, n_devices=0, timeout=900):
+def run_cli(args, n_devices=0, timeout=120):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(BASE, "src")
     if n_devices:

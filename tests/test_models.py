@@ -1,8 +1,7 @@
-"""Per-architecture smoke tests (reduced configs) + model-component tests.
-
-Every assigned arch gets: init -> forward -> loss -> one train step on CPU,
-asserting output shapes and finiteness (the harness smoke contract), plus
-prefill/decode consistency for the families that serve.
+"""Model-component tests (reduced configs): prefill/decode consistency for
+the families that serve, attention tiling, MoE dispatch, the SSD scan,
+parameter counts and remat. The per-architecture smoke is in
+test_arch_smoke.py.
 """
 import dataclasses
 
@@ -15,51 +14,8 @@ from repro.configs import ARCHS, get_config, reduced_for_smoke
 from repro.models import model as M
 from repro.models import moe as moe_mod
 from repro.models.attention import MaskSpec, blockwise_attention, mask_allowed
-from repro.train.step import TrainConfig, init_state, make_train_step
 
-
-def make_batch(cfg, b, s, key):
-    k1, k2, k3 = jax.random.split(key, 3)
-    shape = (b, s) if cfg.family != "audio" else (b, s, cfg.num_codebooks)
-    batch = {
-        "tokens": jax.random.randint(k1, shape, 0, cfg.vocab_size),
-        "labels": jax.random.randint(k2, shape, 0, cfg.vocab_size),
-        "mask": jnp.ones((b, s), jnp.float32),
-    }
-    if cfg.family == "vlm":
-        batch["patches"] = jax.random.normal(
-            k3, (b, cfg.num_prefix_tokens, cfg.d_model), jnp.float32)
-    return batch
-
-
-@pytest.mark.parametrize("arch", ARCHS)
-def test_arch_smoke_forward_and_step(arch):
-    cfg = reduced_for_smoke(get_config(arch))
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    b, s = 2, 64
-    batch = make_batch(cfg, b, s, jax.random.PRNGKey(1))
-
-    logits, aux = M.forward(params, batch, cfg, train=False)
-    text = s  # tokens fed == text length; prefix added inside
-    if cfg.family == "audio":
-        assert logits.shape == (b, text, cfg.num_codebooks, cfg.vocab_size)
-    else:
-        assert logits.shape == (b, text, cfg.vocab_size)
-    assert bool(jnp.all(jnp.isfinite(logits)))
-
-    tcfg = TrainConfig(accum=2)
-    state = init_state(cfg, tcfg, jax.random.PRNGKey(2))
-    step = make_train_step(cfg, tcfg)
-    new_state, metrics = jax.jit(step)(state, batch)
-    assert np.isfinite(float(metrics["loss"]))
-    assert int(new_state.step) == 1
-    # params actually changed
-    delta = jax.tree.reduce(
-        lambda a, l: a + float(jnp.sum(jnp.abs(l[0].astype(jnp.float32)
-                                               - l[1].astype(jnp.float32)))),
-        jax.tree.map(lambda a, b_: (a, b_), new_state.params, state.params),
-        0.0)
-    assert delta > 0.0
+from _model_helpers import make_batch
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "mamba2-1.3b", "hymba-1.5b",
@@ -76,10 +32,14 @@ def test_prefill_decode_matches_forward(arch):
         # capacity non-binding to compare the pure cache math.
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
+    # jitted once each: the decode step is reused for every token
+    forward = jax.jit(lambda p, bt: M.forward(p, bt, cfg, train=False))
+    prefill = jax.jit(lambda p, bt, c: M.prefill(p, bt, cfg, c))
+    decode_step = jax.jit(lambda p, c, tok: M.decode_step(p, c, tok, cfg))
     params = M.init(cfg, jax.random.PRNGKey(0))
     b, s = 2, 32
     batch = make_batch(cfg, b, s, jax.random.PRNGKey(1))
-    full_logits, _ = M.forward(params, batch, cfg, train=False)
+    full_logits, _ = forward(params, batch)
 
     prefix = M.prefix_length(cfg)
     max_len = prefix + s + 8
@@ -87,7 +47,7 @@ def test_prefill_decode_matches_forward(arch):
     n_pre = s // 2
     pre_batch = dict(batch)
     pre_batch["tokens"] = batch["tokens"][:, :n_pre]
-    logits_pre, cache = M.prefill(params, pre_batch, cfg, cache)
+    logits_pre, cache = prefill(params, pre_batch, cache)
     # tolerance is absolute at logit scale: the cached path computes the
     # absorbed MLA/decode math in f32 while the full forward runs bf16
     # denses — measured |Δ|≈0.03-0.05 on ~3.5-scale logits; a cache BUG
@@ -101,7 +61,7 @@ def test_prefill_decode_matches_forward(arch):
     logits_steps = []
     for t in range(n_pre, s):
         tok = batch["tokens"][:, t:t + 1]
-        lg, cache = M.decode_step(params, cache, tok, cfg)
+        lg, cache = decode_step(params, cache, tok)
         logits_steps.append(lg[:, 0])
     got = jnp.stack(logits_steps, axis=1)
     want = full_logits[:, n_pre:]
@@ -168,8 +128,9 @@ def test_moe_dispatch_matches_dense_reference():
     params = init_params(specs, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model),
                           jnp.float32).astype(cfg.activation_dtype)
-    y, aux = moe_mod.moe_apply(params, x, cfg)
-    y_ref = moe_mod.moe_apply_reference(params, x, cfg)
+    (y, aux), y_ref = jax.jit(lambda p, x: (
+        moe_mod.moe_apply(p, x, cfg),
+        moe_mod.moe_apply_reference(p, x, cfg)))(params, x)
     assert float(aux["dropped_frac"]) == 0.0
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32),
@@ -185,7 +146,7 @@ def test_moe_capacity_drops():
     params = init_params(specs, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model)
                           ).astype(cfg.activation_dtype)
-    y, aux = moe_mod.moe_apply(params, x, cfg)
+    y, aux = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg))(params, x)
     assert float(aux["dropped_frac"]) > 0.0
     assert bool(jnp.all(jnp.isfinite(y.astype(jnp.float32))))
 
@@ -283,7 +244,8 @@ def test_remat_modes_same_loss():
     for remat in ["none", "full", "dots"]:
         c = dataclasses.replace(cfg, remat=remat)
         params = M.init(c, jax.random.PRNGKey(0))
-        (l, _), g = jax.value_and_grad(M.loss_fn, has_aux=True)(params, batch, c)
+        (l, _), g = jax.jit(jax.value_and_grad(
+            lambda p, bt, c=c: M.loss_fn(p, bt, c), has_aux=True))(params, batch)
         losses.append(float(l))
         assert all(bool(jnp.all(jnp.isfinite(x))) for x in jax.tree.leaves(g))
     assert max(losses) - min(losses) < 1e-3

@@ -225,20 +225,44 @@ def test_kv_cap_is_bitwise_neutral():
         assert_bitwise(capped, full, f"kv_cap[{t_cap}]")
 
 
+# The fuzzers draw their shapes from fixed sets, so each shape compiles once
+# however many examples draw it; seeds (pool contents, ragged lengths, page
+# tables) are drawn freely. The sets keep the edges of the old ranges
+# (b 1-3, t 1-8, page 4-16, every head, width and split value, both pool
+# dtypes, splits past the table width).
+GQA_FUZZ_SHAPES = [
+    # (b, t, page, hkv, g, dk, dv, dtype, n_splits)
+    (1, 1, 4, 1, 1, 8, 8, "float32", 1),
+    (3, 8, 16, 4, 4, 32, 32, "bfloat16", 8),
+    (2, 2, 8, 2, 1, 16, 8, "float32", 2),
+    (3, 4, 4, 1, 4, 8, 32, "bfloat16", 4),
+    (1, 8, 8, 4, 2, 32, 16, "float32", 8),
+    (2, 1, 16, 2, 2, 16, 16, "bfloat16", 4),
+    (3, 2, 4, 4, 1, 32, 8, "float32", 1),
+    (2, 4, 16, 1, 2, 8, 32, "bfloat16", 2),
+]
+
+MLA_FUZZ_SHAPES = [
+    # (b, t, page, h, c, r, dtype, n_splits)
+    (1, 1, 4, 1, 8, 4, "float32", 1),
+    (3, 8, 16, 8, 32, 16, "bfloat16", 8),
+    (2, 2, 8, 2, 16, 8, "float32", 2),
+    (3, 4, 4, 8, 8, 16, "bfloat16", 4),
+    (1, 8, 8, 2, 32, 4, "float32", 8),
+    (2, 1, 16, 1, 16, 8, "bfloat16", 4),
+    (3, 2, 4, 2, 32, 16, "float32", 1),
+    (2, 4, 16, 8, 8, 4, "bfloat16", 2),
+]
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_gqa_kernel_oracle_fuzz(data):
-    """Property fuzz (hypothesis): random shape/dtype/split/ragged-length
-    draws, Pallas-interpret vs reference (``assert_oracle``)."""
-    b = data.draw(st.integers(1, 3), label="b")
-    t = data.draw(st.sampled_from([1, 2, 4, 8]), label="t")
-    page = data.draw(st.sampled_from([4, 8, 16]), label="page")
-    hkv = data.draw(st.sampled_from([1, 2, 4]), label="hkv")
-    g = data.draw(st.sampled_from([1, 2, 4]), label="g")
-    dk = data.draw(st.sampled_from([8, 16, 32]), label="dk")
-    dv = data.draw(st.sampled_from([8, 16, 32]), label="dv")
-    dtype = data.draw(st.sampled_from(["float32", "bfloat16"]), label="dt")
-    ns = data.draw(st.sampled_from([1, 2, 4, 8]), label="ns")
+    """Property fuzz (hypothesis): shape/dtype/split draws from
+    GQA_FUZZ_SHAPES with random ragged lengths, Pallas-interpret vs
+    reference (``assert_oracle``)."""
+    b, t, page, hkv, g, dk, dv, dtype, ns = data.draw(
+        st.sampled_from(GQA_FUZZ_SHAPES), label="shape")
     seed = data.draw(st.integers(0, 2**31 - 1), label="seed")
     rng = np.random.default_rng(seed)
     q, kp, vp, pt, lens = _gqa_case(rng, b, t, page, hkv, g, dk, dv, dtype)
@@ -252,14 +276,8 @@ def test_gqa_kernel_oracle_fuzz(data):
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_mla_kernel_oracle_fuzz(data):
-    b = data.draw(st.integers(1, 3), label="b")
-    t = data.draw(st.sampled_from([1, 2, 4, 8]), label="t")
-    page = data.draw(st.sampled_from([4, 8, 16]), label="page")
-    h = data.draw(st.sampled_from([1, 2, 8]), label="h")
-    c = data.draw(st.sampled_from([8, 16, 32]), label="c")
-    r = data.draw(st.sampled_from([4, 8, 16]), label="r")
-    dtype = data.draw(st.sampled_from(["float32", "bfloat16"]), label="dt")
-    ns = data.draw(st.sampled_from([1, 2, 4, 8]), label="ns")
+    b, t, page, h, c, r, dtype, ns = data.draw(
+        st.sampled_from(MLA_FUZZ_SHAPES), label="shape")
     seed = data.draw(st.integers(0, 2**31 - 1), label="seed")
     rng = np.random.default_rng(seed)
     ql, qr, cp, rp, pt, lens = _mla_case(rng, b, t, page, h, c, r, dtype)
