@@ -43,6 +43,14 @@ def _med_time(fn, *args, iters=3, reps=5):
     return float(np.median(ts))
 
 
+def _plane_scaled_matmul(x, w, cfg):
+    """``matmul`` of the pow2-prescaled operands (int8 planes in separable
+    mode), scales divided out."""
+    xs, sx = tf._pow2_prescale(x, cfg)
+    ws, sw = tf._pow2_prescale(w, cfg)
+    return tf.matmul(xs, ws, cfg) / (sx * sw)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _legacy_linear(x, w, cfg):
     """The pre-cache training linear (the speedup baseline): raw float
@@ -50,7 +58,7 @@ def _legacy_linear(x, w, cfg):
     full re-decompositions + two materialized transposes per fwd+bwd, none
     of which XLA can CSE against the forward (different chunking axes)."""
     lead = x.shape[:-1]
-    y = tf._scaled_matmul(x.reshape(-1, x.shape[-1]), w, cfg)
+    y = _plane_scaled_matmul(x.reshape(-1, x.shape[-1]), w, cfg)
     return y.reshape(*lead, w.shape[-1])
 
 
@@ -62,8 +70,8 @@ def _legacy_bwd(cfg, res, g):
     x, w = res
     g2 = g.reshape(-1, g.shape[-1])
     x2 = x.reshape(-1, x.shape[-1])
-    dx = tf._scaled_matmul(g2, w.T, cfg).reshape(x.shape).astype(x.dtype)
-    dw = tf._scaled_matmul(x2.T, g2, cfg).astype(w.dtype)
+    dx = _plane_scaled_matmul(g2, w.T, cfg).reshape(x.shape).astype(x.dtype)
+    dw = _plane_scaled_matmul(x2.T, g2, cfg).astype(w.dtype)
     return dx, dw
 
 

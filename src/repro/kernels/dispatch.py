@@ -45,6 +45,18 @@ def current() -> KernelDispatch:
     return _OVERRIDE[-1] if _OVERRIDE else backend_dispatch()
 
 
+def auto_partitioned() -> bool:
+    """True while tracing under a mesh (``jax.set_mesh``, as the launchers
+    enter theirs) of more than one device whose axes are not all manual:
+    XLA partitions that program on its own, and a Pallas (Mosaic) call has
+    no partitioning rule, so a caller runs its jnp form there. Inside a
+    ``shard_map`` over every axis, or with no mesh, it is False."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return False
+    return set(mesh.manual_axes) != set(mesh.axis_names)
+
+
 def resolve(use_pallas: Optional[bool] = None,
             interpret: Optional[bool] = None) -> KernelDispatch:
     """Per-call kwargs beat the active policy; None defers to it."""

@@ -58,6 +58,21 @@ def _primal(fn, *args):
     return np.asarray(jax.jit(fn)(*args))
 
 
+def _plane_scaled_matmul(x2, w, cfg):
+    """The forward as ``matmul`` of the pow2-prescaled operands, apart from
+    the prepared operands: int8 planes in separable/pallas mode, the
+    oracle in exact mode."""
+    xs, sx = tf._pow2_prescale(x2, cfg)
+    ws, sw = tf._pow2_prescale(w, cfg)
+    return tf.matmul(xs, ws, cfg) / (sx * sw)
+
+
+def _plane_forward(x, w, cfg):
+    """_plane_scaled_matmul over x's leading dims, jitted."""
+    return _primal(lambda a, b: _plane_scaled_matmul(
+        a.reshape(-1, a.shape[-1]), b, cfg).reshape(*a.shape[:-1], -1), x, w)
+
+
 # prepare_weight of one weight, and of a stack of them over the leading
 # dims (vmapped once per dim), jitted once per shape and mode (the stacking
 # law is about values).
@@ -101,19 +116,22 @@ def test_cached_vs_uncached_bit_identical(mode):
     y_c, dx_c, dw_c = _run(lambda a, b: tf.linear(a, b, cfg_c), x, w, g)
     y_u, dx_u, dw_u = _run(lambda a, b: tf.linear(a, b, cfg_u), x, w, g)
     np.testing.assert_array_equal(y_c, y_u)
+    np.testing.assert_array_equal(y_c, _plane_forward(x, w, cfg_c))
     np.testing.assert_array_equal(dx_c, dx_u)
     np.testing.assert_array_equal(dw_c, dw_u)
 
 
 @pytest.mark.parametrize("mode", MODES)
 def test_fwd_primal_matches_vjp_fwd(mode):
-    """linear() outside autodiff == the custom_vjp forward (the prepared
-    path must reproduce _scaled_matmul bit-for-bit)."""
+    """linear() outside autodiff == the custom_vjp forward, and both ==
+    matmul() of the prescaled operands through the planes (the oracle in
+    exact mode), bit-for-bit."""
     x, w, g = _data(key=1)
     cfg = TFConfig(mode=mode)
     y_p = _primal(lambda a, b: tf.linear(a, b, cfg), x, w)
     y_f, _, _ = _run(lambda a, b: tf.linear(a, b, cfg), x, w, g)
     np.testing.assert_array_equal(y_p, y_f)
+    np.testing.assert_array_equal(y_p, _plane_forward(x, w, cfg))
 
 
 @pytest.mark.parametrize("mode", MODES)
@@ -138,11 +156,36 @@ def test_exact_mode_matches_precache_backward():
     _, dx, dw = _run(lambda a, b: tf.linear(a, b, cfg), x, w, g)
     g2 = g.reshape(-1, g.shape[-1])
     x2 = x.reshape(-1, x.shape[-1])
-    legacy_dx = _primal(lambda a, b: tf._scaled_matmul(a, b, cfg),
+    legacy_dx = _primal(lambda a, b: _plane_scaled_matmul(a, b, cfg),
                         g2, w.T).reshape(x.shape)
-    legacy_dw = _primal(lambda a, b: tf._scaled_matmul(a, b, cfg), x2.T, g2)
+    legacy_dw = _primal(lambda a, b: _plane_scaled_matmul(a, b, cfg),
+                        x2.T, g2)
     np.testing.assert_array_equal(dx, legacy_dx)
     np.testing.assert_array_equal(dw, legacy_dw)
+
+
+@pytest.mark.parametrize("cache", [True, False], ids=["cached", "uncached"])
+def test_separable_backward_matches_plane_reads(cache):
+    """Separable mode's dx and dW from the aligned values == the transposed
+    reads of the int8 planes (matmul_separable_transposed / _outer), bit
+    for bit: the backward reads the same operands as through the planes."""
+    x, w, g = _data(key=7)
+    cfg = TFConfig(mode="separable", cache=cache)
+    _, dx, dw = _run(lambda a, b: tf.linear(a, b, cfg), x, w, g)
+
+    def planes(x2, w, g2):
+        xs, sx = tf._pow2_prescale(x2, cfg)
+        ws, sw = tf._pow2_prescale(w, cfg)
+        gs, sg = tf._pow2_prescale(g2, cfg)
+        k = x2.shape[1]
+        qx, qw = tf.quantize_input(xs, cfg), tf.quantize_weight(ws, cfg)
+        return (tf.matmul_separable_transposed(gs, qw, k, cfg) / (sg * sw),
+                tf.matmul_separable_outer(qx, gs, k, cfg) / (sx * sg))
+
+    want_dx, want_dw = jax.jit(planes)(x.reshape(-1, x.shape[-1]), w,
+                                       g.reshape(-1, g.shape[-1]))
+    np.testing.assert_array_equal(dx, np.asarray(want_dx).reshape(x.shape))
+    np.testing.assert_array_equal(dw, np.asarray(want_dw))
 
 
 def test_separable_transposed_read_tracks_f32_gradients():
@@ -247,8 +290,8 @@ def test_build_weight_cache_filters():
     # -> dense_in rule (16, 8)
     wq = cache.groups[0]["['mixer']['wq']"]
     wo = cache.groups[0]["['mixer']['wo']"]
-    assert wq.q.q.shape[0] == 2 and wq.scale.shape == (2,)
-    assert wq.q.q.shape[-1] == 16 and wo.q.q.shape[-1] == 8
+    assert wq.v.shape == (2, 8, 16) and wq.scale.shape == (2,)
+    assert wo.v.shape == (2, 16, 8)
     off = dataclasses.replace(model_cfg, quant="none")
     assert common.build_weight_cache(params, off) is None
     hatch = dataclasses.replace(
@@ -265,7 +308,7 @@ def test_build_weight_cache_tied_head_entry():
     cache = common.build_weight_cache(params, model_cfg)
     assert sorted(cache.flat) == ["['embed']"]
     pw = cache.flat["['embed']"]
-    assert pw.q.q.shape[-1] == 32  # prepared for the (8, 32) transposed read
+    assert pw.v.shape == (8, 32)  # prepared for the (8, 32) transposed read
 
 
 # ---------------------------------------------------------------------------

@@ -118,19 +118,19 @@ def test_mapper_agrees_with_weight_cache_rules(arch):
 
 
 def test_mapper_shapes_match_prepared_operands():
-    """Placed (rows, cols) equal the stored int8 plane geometry of the
-    cache entry for flat dense/dense_in leaves (separable mode)."""
+    """Placed (rows, cols) equal the stored operand geometry of the cache
+    entry for flat dense/dense_in leaves (separable mode: the (K, N)
+    block-aligned values)."""
     cfg = _tf_cfg(reduced_for_smoke(get_config("deepseek-v3-671b")))
     params, cache = _param_and_cache_shapes(cfg)
     pl = map_params(params, cfg)
     by_key = {lp.key: lp for lp in pl.leaves if lp.group is None}
     for key, ent in cache.flat.items():
         lp = by_key[key]
-        c, b, n = ent.q.q.shape  # (C, B, N): C*B = padded K
-        assert n == lp.cols
-        assert (c - 1) * b < lp.rows <= c * b
+        k, n = ent.v.shape
+        assert (k, n) == (lp.rows, lp.cols)
         # tile rows == quantization block: the K tiling IS the chunking
-        assert lp.tiles_r == c
+        assert lp.tiles_r == -(-k // cfg.tf.block)
 
 
 def test_shape_only_mapping_equals_param_mapping():

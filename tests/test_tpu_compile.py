@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.timefloats import QuantizedOperand, TFConfig
 from repro.kernels import ops
+from repro.kernels.block_align import block_align_pallas
 from repro.kernels.paged import gather_pages_pallas
 from repro.kernels.paged_attn import paged_decode_attention, paged_decode_mla
 from repro.kernels.sampling import sample_tokens
@@ -74,6 +75,52 @@ def test_transposed_matmul_compiles(spec, m, n, k):
     _assert_kernel(partial(ops.timefloats_matmul_transposed, k_dim=k,
                            cfg=CFG, interpret=False),
                    spec((m, n), jnp.float32), qw)
+
+
+@pytest.mark.parametrize("shape,dt,axes", [
+    ((M_TRAIN, D), jnp.bfloat16, (1,)),         # a layer input
+    ((M_TRAIN, FFW), jnp.float32, (1, 0)),      # a cotangent, both reads
+    ((M_TRAIN, VOCAB), jnp.float32, (1, 0)),    # the head's cotangent
+    ((SLOTS, D), jnp.bfloat16, (1,)),           # a decode step's input
+    ((28, D, FFW), jnp.float32, (0,)),          # a layer stack's weights
+], ids=["input", "cotangent", "head", "decode", "weights"])
+def test_block_align_compiles(spec, shape, dt, axes):
+    fn = partial(block_align_pallas, axes=axes, block=CFG.block, fmt=CFG.fmt)
+    if len(shape) == 3:
+        fn = jax.vmap(fn)
+    _assert_kernel(fn, spec(shape, dt), spec(shape[:-2], jnp.float32))
+
+
+def test_sharded_linear_compiles_without_kernel(topo, spec):
+    """The separable linear's forward and backward under a 2x2 mesh, with
+    the Pallas kernels asked for: XLA partitions the program, so the
+    aligner takes its jnp form (a Mosaic call cannot be partitioned) and
+    the program compiles with no custom call. On one chip the kernel is
+    there."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import timefloats as tf
+    from repro.kernels import dispatch
+
+    cfg = TFConfig(mode="separable")
+
+    def grads(x, w):
+        return jax.grad(lambda x, w: jnp.sum(
+            tf.linear(x, w, cfg).astype(jnp.float32) ** 2), (0, 1))(x, w)
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    sharded = [jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(
+        mesh, PartitionSpec(*axes))) for shape, dt, axes in (
+            ((M_TRAIN, D), jnp.bfloat16, ("data", None)),
+            ((D, FFW), jnp.float32, (None, "model")))]
+    with dispatch.override(use_pallas=True, interpret=False):
+        with jax.set_mesh(mesh):
+            text = jax.jit(grads).lower(*sharded).compile().as_text()
+        assert "tpu_custom_call" not in text
+        _assert_kernel(grads, spec((M_TRAIN, D), jnp.bfloat16),
+                       spec((D, FFW), jnp.float32))
 
 
 def test_gqa_paged_decode_compiles(spec):
