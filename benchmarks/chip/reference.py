@@ -1,10 +1,12 @@
-"""Plain reference of the trained model, in float32.
+"""The parts of the plain float32 reference that every architecture
+shares, importing nothing of the program: the TimeFloats E4M4 scalar
+product, the norms and rotations, the loss, AdamW over the check steps,
+and the norms that the check compares. Each architecture's own forward
+pass, sizes, weights plan and work count live in ``archs/<name>.py``,
+which the configuration file names under ``"reference"``.
 
-Written from the semantics the configurations state, and importing
-nothing of the program: a dense pre-norm decoder (RMSNorm, rotary
-embeddings on the split halves, optional q/k RMSNorm, grouped-query
-causal attention, SwiGLU) whose every projection matmul is a TimeFloats
-E4M4 scalar product:
+Every projection matmul of a reference is a TimeFloats E4M4 scalar
+product (``tf_matmul``):
 
 1. each operand is scaled by a power of two so that its largest magnitude
    has exponent 7 (stored code 14 of the 4-bit exponent, bias 7);
@@ -17,11 +19,8 @@ E4M4 scalar product:
    the two power-of-two scales are removed.
 
 Everything else runs in float32 at ``highest`` matmul precision. The
-control is this reference one precision step below the configuration:
+control is the reference one precision step below the configuration:
 ``man_bits=3``, E4M3 scalar products where the configuration states E4M4.
-
-Parameters are read from the program's parameter layout (stacked layers
-under ``groups[0]['params']``), as one would read a checkpoint.
 """
 from __future__ import annotations
 
@@ -133,75 +132,9 @@ def rope(x, pos, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def forward(params, tokens, real, spec: dict, man_bits: int = MAN_BITS,
-            remat: bool = False):
-    """Logits (B, S, V) of ``tokens (B, S)``. ``real (B, S)`` is 1 at real
-    positions and 0 at padding: padding is kept out of every operand's
-    prescale and, being at the end of a row, out of every real position's
-    attention. Every projection takes all B * S rows as one operand, as
-    the program's does."""
-    b, s_len = tokens.shape
-    keep = real[..., None].astype(jnp.float32)
-    lin = lambda a, w: tf_matmul(a * keep, w.reshape(w.shape[0], -1),
-                                 man_bits)
-    h_q, h_kv = spec["n_heads"], spec["n_kv_heads"]
-    hd = spec["head_dim"]
-    eps = spec["rms_norm_eps"]
-    pos = jnp.arange(s_len)
-    causal = pos[None, :] <= pos[:, None]
-    if spec.get("sliding_window"):
-        causal &= pos[:, None] - pos[None, :] < spec["sliding_window"]
-    lp = params["groups"][0]["params"]
-
-    def layer(x, p):
-        h = rms_norm(x, p["norm1"]["scale"], eps)
-        a = p["mixer"]
-        q = lin(h, a["wq"]).reshape(b, s_len, h_q, hd)
-        k = lin(h, a["wk"]).reshape(b, s_len, h_kv, hd)
-        v = lin(h, a["wv"]).reshape(b, s_len, h_kv, hd)
-        if "q_norm" in a:
-            q = rms_norm(q, a["q_norm"], eps)
-            k = rms_norm(k, a["k_norm"], eps)
-        q = rope(q, pos, spec["rope_theta"])
-        k = rope(k, pos, spec["rope_theta"])
-        g = h_q // h_kv
-        qg = q.reshape(b, s_len, h_kv, g, hd)
-        sc = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
-                        precision=jax.lax.Precision.HIGHEST) / math.sqrt(hd)
-        sc = jnp.where(causal, sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        o = jnp.einsum("bkgqs,bskd->bqkgd", pr, v,
-                       precision=jax.lax.Precision.HIGHEST)
-        o = o.reshape(b, s_len, h_q * hd)
-        x = x + lin(o, a["wo"].reshape(h_q * hd, -1))
-        h2 = rms_norm(x, p["norm2"]["scale"], eps)
-        f = p["ffn"]
-        u = jax.nn.silu(lin(h2, f["w_gate"])) * lin(h2, f["w_up"])
-        x = x + lin(u, f["w_down"])
-        return x, None
-
-    if remat:
-        layer = jax.checkpoint(layer)
-    x = params["embed"][tokens].astype(jnp.float32)
-    x, _ = jax.lax.scan(layer, x, lp)
-    x = rms_norm(x, params["final_norm"]["scale"], eps)
-    head = (params["embed"].T if "lm_head" not in params
-            else params["lm_head"])
-    return tf_matmul(x * keep, head, man_bits)
-
-
-def model_spec(config: dict) -> dict:
-    """The sizes the reference needs, from the configuration file."""
-    return {"n_heads": config["num_attention_heads"],
-            "n_kv_heads": config["num_key_value_heads"],
-            "head_dim": config["head_dim"],
-            "rope_theta": float(config["rope_theta"]),
-            "rms_norm_eps": float(config["rms_norm_eps"]),
-            "sliding_window": config.get("sliding_window")}
-
-
-def loss(params, batch, spec: dict, man_bits: int = MAN_BITS):
-    """Mean next-token negative log-likelihood over the masked positions."""
+def loss(params, batch, forward, spec: dict, man_bits: int = MAN_BITS):
+    """Mean next-token negative log-likelihood over the masked positions,
+    of the logits an architecture's ``forward`` gives."""
     tok = batch["tokens"]
     logits = forward(params, tok, jnp.ones(tok.shape, jnp.float32), spec,
                      man_bits=man_bits, remat=True)
@@ -254,16 +187,17 @@ def change_norms(new, old, rows):
     return leaf_norms(d, rows), leaf_norms(d, ~rows)
 
 
-def train_steps(make_params, batches, spec: dict, opt: dict, rows,
-                man_bits: int = MAN_BITS):
-    """AdamW over ``batches`` from ``make_params()``, with the gradient
+def train_steps(make_params, batches, forward, spec: dict, opt: dict,
+                rows, man_bits: int = MAN_BITS):
+    """AdamW over ``batches`` from ``make_params()``, the model being an
+    architecture's ``forward`` at the sizes ``spec``, with the gradient
     clipped to a global norm of ``grad_clip`` first. Returns the losses,
     the per-leaf norms of the first (clipped) gradient, and the per-leaf
     norms of the parameters' change over all the steps, a token-indexed
     table's over the rows ``rows`` marks and apart over the rest (the
     starting parameters are made again for that, rather than kept)."""
     grad = jax.jit(jax.value_and_grad(
-        lambda p, b: loss(p, b, spec, man_bits)))
+        lambda p, b: loss(p, b, forward, spec, man_bits)))
 
     @jax.jit
     def update(p, m, v, g, t, lr):

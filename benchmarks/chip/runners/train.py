@@ -56,9 +56,10 @@ def feed(cfg, mix: dict, seed: int):
     return mesh, rules, pipe
 
 
-def build(cfg, mix: dict, seed: int):
+def build(cfg, arch, mix: dict, seed: int):
     """(jitted step, the step before jit, state, pipeline, mesh, param
-    shardings) for a cell."""
+    shardings) for a cell whose weights the architecture module ``arch``
+    plans."""
     from repro.optim.optimizers import OptimizerConfig, make_optimizer
     from repro.parallel import sharding as shd
     from repro.train import step as tsl
@@ -72,14 +73,14 @@ def build(cfg, mix: dict, seed: int):
     optimizer = make_optimizer(tcfg.optimizer)
 
     def shapes(_):
-        p = jax.eval_shape(lambda: weights.make_params(cfg, 0))
+        p = jax.eval_shape(lambda: weights.make_params(cfg, 0, arch))
         return tsl.TrainState(step=jax.ShapeDtypeStruct((), jnp.int32),
                               params=p, opt=jax.eval_shape(optimizer.init, p),
                               rng=jax.ShapeDtypeStruct((2,), jnp.uint32))
 
     s_shard = shd.tree_shardings(tsl.state_axes(cfg, tcfg), shapes(None),
                                  mesh, rules)
-    params = weights.make_params(cfg, seed, shardings=s_shard.params)
+    params = weights.make_params(cfg, seed, arch, shardings=s_shard.params)
     opt = jax.jit(optimizer.init, out_shardings=s_shard.opt)(params)
     state = tsl.TrainState(step=jax.device_put(jnp.zeros((), jnp.int32),
                                                s_shard.step),
@@ -157,11 +158,13 @@ def checks(gaps: dict, finite: bool, limits: dict) -> dict:
 
 def reference_steps(cell, cfg, seed: int, batches, rows, p_shard=None,
                     **kw):
-    """The reference over the check steps, from the seed's weights."""
-    mix = cell.traffic
+    """The reference over the check steps, from the seed's weights: the
+    forward pass of the cell's architecture module, at its sizes."""
+    arch = cell.arch
     got = reference.train_steps(
-        lambda: weights.make_params(cfg, seed, shardings=p_shard), batches,
-        reference.model_spec(cell.config), _opt(mix), rows, **kw)
+        lambda: weights.make_params(cfg, seed, arch, shardings=p_shard),
+        batches, arch.forward, arch.model_spec(cell.config),
+        _opt(cell.traffic), rows, **kw)
     return dict(zip(("losses", "grad", "change", "rest"), got))
 
 
@@ -184,7 +187,7 @@ def run(cell, seed, seconds, trace, *, device, chips, t_start, fault=None):
     returns the jitted step the run uses."""
     mix = cell.traffic
     cfg = spec.model_config(cell.config)
-    jitted, fn, state, pipe, mesh, p_shard = build(cfg, mix, seed)
+    jitted, fn, state, pipe, mesh, p_shard = build(cfg, cell.arch, mix, seed)
     if fault is not None:
         jitted = fault(fn)
     n_check = int(mix["check_steps"])
@@ -204,7 +207,8 @@ def run(cell, seed, seconds, trace, *, device, chips, t_start, fault=None):
                 prog["grad"] = jax.tree.map(lambda x: x / (1 - b1),
                                             _norms(state.opt["m"]))
         prog["change"], prog["rest"] = jax.device_get(reference.change_norms(
-            state.params, weights.make_params(cfg, seed, shardings=p_shard),
+            state.params,
+            weights.make_params(cfg, seed, cell.arch, shardings=p_shard),
             jnp.asarray(rows)))
         prof = Profiler(bool(trace), float(mix.get("trace_s", seconds)),
                         TRACE_DIR / cell.name)
@@ -255,7 +259,7 @@ def run(cell, seed, seconds, trace, *, device, chips, t_start, fault=None):
         n_tr = sum(1 for t in times if prof.lo <= t <= prof.hi)
         ctx = {"trace": tracing.load(str(prof.out)),
                "span_s": prof.hi - prof.lo,
-               "flops": n_tr * work.train_flops(
+               "flops": n_tr * cell.arch.train_flops(
                    cell.config, mix["batch"], mix["seq"]),
                "config": cell.config, "peaks": work.peaks(device["kind"]),
                "chips": chips}

@@ -20,8 +20,14 @@ ROOT = spec.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_./-]{1,200}$")
-WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|head)|_dim$|"
-                   r"_rank$|expansion|experts_per_tok|num_experts_per")
+# Keys that state a width, which no cut to a chip may change: hidden,
+# intermediate, latent, state, projection and window sizes, head counts and
+# sizes, ranks, expansion factors and the experts each token is routed to.
+# Counts of layers, of experts and of vocabulary rows are scale, and a cut
+# may change them if ``reduced`` lists them.
+WIDTH = re.compile(r"hidden_size|intermediate|latent|state|proj|window|"
+                   r"head|_dim$|_rank$|expan|experts_per_tok|top_?k|"
+                   r"d_model|n_embd|d_ff|conv")
 E2E_SOURCES = {"host_clock", "device_trace"}
 SOURCES = E2E_SOURCES | {"program_span", "program_counter"}
 CELLS = {w["name"]: w for w in BENCH["workloads"]}
@@ -110,6 +116,41 @@ def rule_entries_have_only_their_keys():
                                           "layer", "moves"}, m
 
 
+def config_matches_its_file(entry: dict, on_disk: dict):
+    assert entry["source"].startswith("https://") and _line(entry["source"])
+    assert _line(entry["why"])
+    assert on_disk["source"] == entry["source"]
+    assert sorted(on_disk["reduced"]) == sorted(entry["reduced"])
+
+
+def config_reduces_no_width(entry: dict, on_disk: dict):
+    assert len(entry["reduced"]) <= 16
+    for k in entry["reduced"]:
+        assert NAME.match(k), (entry["name"], k)
+        assert not WIDTH.search(k), (entry["name"], k)
+
+
+def config_publishes_what_it_reduced(entry: dict, on_disk: dict):
+    """Each key in ``reduced`` is a key of the file, whose ``published``
+    gives the source's value of exactly those keys, each a value the
+    file's own differs from."""
+    published = on_disk.get("published", {})
+    assert set(published) == set(entry["reduced"]), (entry["name"],
+                                                     published)
+    for k in entry["reduced"]:
+        assert k in on_disk, (entry["name"], k)
+        assert published[k] != on_disk[k], (entry["name"], k)
+
+
+CONFIG_RULES = (config_matches_its_file, config_reduces_no_width,
+                config_publishes_what_it_reduced)
+
+
+def _each_config(check):
+    for c in BENCH["configs"]:
+        check(c, json.loads((ROOT / c["file"]).read_text()))
+
+
 def rule_configs_are_files_under_paths_used_by_a_cell():
     confs = BENCH["configs"]
     assert 1 <= len(confs) <= 24
@@ -120,18 +161,15 @@ def rule_configs_are_files_under_paths_used_by_a_cell():
         assert c["name"] in used, c["name"]
         assert any(c["file"].startswith(p.rstrip("/") + "/")
                    for p in BENCH["paths"])
-        assert c["source"].startswith("https://") and _line(c["source"])
-        assert _line(c["why"])
-        on_disk = json.loads((ROOT / c["file"]).read_text())
-        assert on_disk["source"] == c["source"]
-        assert sorted(on_disk["reduced"]) == sorted(c["reduced"])
+    _each_config(config_matches_its_file)
 
 
 def rule_reduced_names_no_width():
-    for c in BENCH["configs"]:
-        assert len(c["reduced"]) <= 16
-        for k in c["reduced"]:
-            assert not WIDTH.search(k), (c["name"], k)
+    _each_config(config_reduces_no_width)
+
+
+def rule_reduced_keys_are_published_and_differ():
+    _each_config(config_publishes_what_it_reduced)
 
 
 def rule_cells_name_a_config_a_mix_and_their_chips():
@@ -233,3 +271,58 @@ RULES = [f for n, f in sorted(globals().items()) if n.startswith("rule_")]
 @pytest.mark.parametrize("rule", RULES, ids=[f.__name__[5:] for f in RULES])
 def test_benchmark_file_keeps_the_rule(rule):
     rule()
+
+
+# A configuration cut to one chip's share of an expert-parallel deployment:
+# six of 27 layers, eight of 64 experts, an eighth of the vocabulary.
+SOURCE = ("https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/"
+          "config.json")
+CUT = {"num_hidden_layers": (6, 27), "n_routed_experts": (8, 64),
+       "vocab_size": (20480, 163840)}
+WIDTHS = {"hidden_size": (1024, 2048), "moe_intermediate_size": (704, 1408),
+          "kv_lora_rank": (256, 512), "num_experts_per_tok": (3, 6)}
+
+
+def _cut_config(changes: dict, published: dict = None, absent=()):
+    """(entry, file) of a configuration that changes ``changes`` (key ->
+    (file's value, source's value)) and lists them in ``reduced``; the
+    keys ``absent`` are left out of the file."""
+    on_disk = {"name": "cut", "source": SOURCE, "hidden_size": 2048,
+               "moe_intermediate_size": 1408, "kv_lora_rank": 512,
+               "num_experts_per_tok": 6, "num_hidden_layers": 27,
+               "n_routed_experts": 64, "vocab_size": 163840}
+    on_disk.update({k: mine for k, (mine, _src) in changes.items()
+                    if k not in absent})
+    on_disk["reduced"] = list(changes)
+    on_disk["published"] = (published if published is not None else
+                            {k: src for k, (_mine, src) in changes.items()})
+    entry = {"name": "cut", "source": SOURCE, "file": "cut.json",
+             "reduced": list(changes), "why": "latent attention, experts"}
+    return entry, on_disk
+
+
+# case -> (entry, file), and the check that refuses it (None: admitted).
+REDUCED_CASES = {
+    "depth-experts-vocab": (_cut_config(CUT), None),
+    **{f"width-{k}": (_cut_config({k: v}), config_reduces_no_width)
+       for k, v in WIDTHS.items()},
+    "key-not-in-file": (_cut_config({"num_nextn_predict_layers": (0, 1)},
+                                    absent=("num_nextn_predict_layers",)),
+                        config_publishes_what_it_reduced),
+    "published-equals-file": (_cut_config(
+        CUT, {"num_hidden_layers": 6, "n_routed_experts": 64,
+              "vocab_size": 163840}), config_publishes_what_it_reduced),
+    "changed-and-not-published": (_cut_config(CUT, {}),
+                                  config_publishes_what_it_reduced),
+}
+
+
+@pytest.mark.parametrize("case", REDUCED_CASES)
+def test_reduced_admits_depth_and_refuses_widths(case):
+    (entry, on_disk), refuses = REDUCED_CASES[case]
+    for check in CONFIG_RULES:
+        if check is refuses:
+            with pytest.raises(AssertionError):
+                check(entry, on_disk)
+        else:
+            check(entry, on_disk)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import tiny  # noqa: F401  (puts the program and the benchmark on the path)
-from benchmarks.chip import reference, spec, tracing, work
+from benchmarks.chip import reference, spec, tracing, weights, work
 
 HERE = Path(__file__).resolve().parent
 CHIP = HERE.parent
@@ -132,18 +132,138 @@ def test_reference_matmul_is_exact_on_aligned_operands():
 
 
 def test_work_counts_follow_the_shapes():
-    c = spec.load_cell(CELLS[0]).config
+    cell = spec.load_cell(CELLS[0])
+    c, arch = cell.config, cell.arch
     d, f = c["hidden_size"], c["intermediate_size"]
     h, kv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
                  c["head_dim"])
-    assert work.layer_matmul_params(c) == (d * h * hd + 2 * d * kv * hd
+    assert arch.layer_matmul_params(c) == (d * h * hd + 2 * d * kv * hd
                                            + h * hd * d + 3 * d * f)
     assert work.causal_pairs(4) == 10
-    per_token = 2 * c["num_hidden_layers"] * work.layer_matmul_params(c)
+    per_token = 2 * c["num_hidden_layers"] * arch.layer_matmul_params(c)
     head = 2 * d * c["vocab_size"]
     pairs = 4 * c["num_hidden_layers"] * h * hd * work.causal_pairs(4)
-    assert work.train_flops(c, 1, 4) == 3 * (4 * per_token + pairs
+    assert arch.train_flops(c, 1, 4) == 3 * (4 * per_token + pairs
                                              + 4 * head)
+
+
+ARCH_PARTS = ("model_spec", "forward", "CONTRACT", "ONES", "EMBED",
+              "train_flops")
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_architecture_module_found_by_name(name):
+    entry = {c["name"]: c for c in BENCH["configs"]}[name]
+    config = json.loads((CHIP.parents[1] / entry["file"]).read_text())
+    arch = spec.arch(config, entry["file"])
+    assert Path(arch.__file__) == CHIP / "archs" / f"{config['reference']}.py"
+    assert all(hasattr(arch, part) for part in ARCH_PARTS)
+    cell = next(w["name"] for w in BENCH["workloads"] if w["config"] == name)
+    assert spec.load_cell(cell).arch.__file__ == arch.__file__
+
+
+@pytest.mark.parametrize("reference, named", [
+    (None, "qwen3-0.6b.json"), ("no_such_arch", "archs/no_such_arch.py")],
+    ids=["names-none", "module-missing"])
+def test_missing_architecture_module_stops_the_run(
+        reference, named, tmp_path, capsys, monkeypatch):
+    from benchmarks.chip import run
+
+    bench = json.loads(json.dumps(BENCH))
+    entry = {c["name"]: c for c in bench["configs"]}[
+        spec.load_cell(CELLS[0]).config_name]
+    config = json.loads((CHIP.parents[1] / entry["file"]).read_text())
+    config.pop("reference")
+    if reference:
+        config["reference"] = reference
+    entry["file"] = str(tmp_path / Path(entry["file"]).name)
+    Path(entry["file"]).write_text(json.dumps(config))
+    monkeypatch.setattr(spec, "load_benchmark", lambda: bench)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
+    rc = run.main(["--workload", CELLS[0], "--seed", "1", "--seconds", "1",
+                   "--trace", "0"])
+    out = capsys.readouterr()
+    assert rc != 0 and out.out.strip() == ""
+    assert "no result" in out.err and named in out.err, out.err
+
+
+def _program_leaves(cfg):
+    import jax
+
+    from repro.models import model as model_lib
+
+    shapes = jax.eval_shape(lambda k: model_lib.init(cfg, k),
+                            jax.random.PRNGKey(0))
+    return jax.tree_util.tree_flatten_with_path(shapes)[0]
+
+
+def test_weights_plan_covers_every_leaf_of_the_program():
+    c = tiny.cell(CELLS[0], {})
+    flat = _program_leaves(spec.model_config(c.config))
+    kinds = weights.plan(flat, c.arch)     # raises on a leaf it lacks
+    assert len(kinds) == len(flat)
+    assert {k for k, _ in kinds} == {"ones", "normal"}
+
+
+def test_weights_plan_lacking_a_leaf_names_it():
+    import types
+
+    c = tiny.cell(CELLS[0], {})
+    stub = types.ModuleType("stub_arch")
+    stub.ONES, stub.EMBED = c.arch.ONES, c.arch.EMBED
+    stub.CONTRACT = {k: v for k, v in c.arch.CONTRACT.items() if k != "wq"}
+    with pytest.raises(ValueError, match="'wq'"):
+        weights.plan(_program_leaves(spec.model_config(c.config)), stub)
+
+
+# A tiny deepseek-v3 program (latent attention, routed and shared experts,
+# one leading dense layer) and the file that states its widths.
+TINY_MLA_MOE = {
+    "num_hidden_layers": 2, "hidden_size": 128, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "intermediate_size": 384, "vocab_size": 512,
+    "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 32,
+    "qk_rope_head_dim": 16, "v_head_dim": 32, "moe_intermediate_size": 64,
+    "n_routed_experts": 8, "num_experts_per_tok": 2, "n_shared_experts": 1,
+    "first_k_dense_replace": 1}
+TINY_MLA_MOE_PROGRAM = {
+    "n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 4,
+    "d_ff": 256, "vocab_size": 512,
+    "mla": {"q_lora_rank": None, "kv_lora_rank": 32, "qk_nope_head_dim": 32,
+            "qk_rope_head_dim": 16, "v_head_dim": 32},
+    "moe": {"num_experts": 8, "top_k": 2, "d_expert": 64, "num_shared": 1,
+            "first_k_dense": 1, "dense_d_ff": 384, "shared_d_ff": 64}}
+
+
+def _tiny_mla_moe(file_change=None, q_lora_rank=None):
+    program = dict(TINY_MLA_MOE_PROGRAM,
+                   mla=dict(TINY_MLA_MOE_PROGRAM["mla"],
+                            q_lora_rank=q_lora_rank))
+    return dict(TINY_MLA_MOE, **(file_change or {}), name="tiny-mla-moe",
+                program={"arch": "deepseek-v3-671b", "overrides": program})
+
+
+def test_latent_and_expert_widths_equal_to_the_programs_pass():
+    cfg = spec.model_config(_tiny_mla_moe())
+    assert cfg.mla.q_lora_rank is None and cfg.mla.kv_lora_rank == 32
+    assert cfg.moe.num_experts == 8 and cfg.moe.dense_d_ff == 384
+
+
+# One width differs. The file's intermediate_size is the leading dense
+# layer's width (384), so the program's d_ff (256) is no match for it; a
+# null in the file is None in the program, and nothing else.
+@pytest.mark.parametrize("key, value, q_lora_rank", [
+    ("kv_lora_rank", 64, None), ("qk_nope_head_dim", 64, None),
+    ("qk_rope_head_dim", 32, None), ("v_head_dim", 64, None),
+    ("moe_intermediate_size", 128, None), ("n_routed_experts", 16, None),
+    ("num_experts_per_tok", 4, None), ("n_shared_experts", 2, None),
+    ("first_k_dense_replace", 2, None), ("intermediate_size", 256, None),
+    ("q_lora_rank", 16, None), ("q_lora_rank", None, 1536)],
+    ids=lambda v: str(v))
+def test_latent_and_expert_width_that_differs_is_refused(key, value,
+                                                         q_lora_rank):
+    config = _tiny_mla_moe({key: value}, q_lora_rank)
+    with pytest.raises(ValueError, match=key):
+        spec.model_config(config)
 
 
 def test_peaks_refuse_an_unknown_device():

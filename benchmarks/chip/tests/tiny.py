@@ -25,11 +25,11 @@ def cell(name: str, limits: dict):
     seconds, with the given limits. It need not be in BENCHMARK.json: the
     configuration and the mix are read from their files."""
     config_name, mix = name.rsplit(".", 1)
+    file = spec.HERE / "configs" / f"{config_name}.json"
+    config = json.loads(file.read_text())
     c = spec.Cell(
-        name=name, chips=1, config_name=config_name,
-        config=json.loads((spec.HERE / "configs" / f"{config_name}.json")
-                          .read_text()),
-        traffic_name=mix,
+        name=name, chips=1, config_name=config_name, config=config,
+        arch=spec.arch(config, str(file)), traffic_name=mix,
         traffic=json.loads((spec.HERE / "traffic" / f"{mix}.json")
                            .read_text()),
         limits={}, end_to_end=[], per_layer=[])
